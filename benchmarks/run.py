@@ -19,6 +19,7 @@ import sys
 import traceback
 
 from benchmarks.common import LOAD_THRESHOLD, machine_load, out_dir
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = ("characterization", "microbench", "redis_like",
            "llm_inference", "vectordb", "tiered_memory", "roofline")
@@ -38,6 +39,7 @@ def main() -> int:
         p.error(f"unknown benchmark modules {unknown}; "
                 f"choose from {','.join(MODULES)}")
 
+    enable_compile_cache()
     # create experiments/bench/ up front so a missing output directory can
     # never surface as a module failure mid-run.
     out_dir()

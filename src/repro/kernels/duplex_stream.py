@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _dequant_block(q, scale, dtype):
     return (q.astype(jnp.float32) * scale).astype(dtype)
@@ -96,7 +94,8 @@ def dequant_stream(in_q, in_scale, *, interpret: bool = False):
         in_specs=[s["q"], s["scale"]],
         out_specs=s["x"],
         out_shape=jax.ShapeDtypeStruct((N, T, D), jnp.bfloat16),
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(in_q, in_scale)
 
@@ -117,7 +116,8 @@ def quant_stream(out_x, *, interpret: bool = False):
             jax.ShapeDtypeStruct((N, T, D), jnp.int8),
             jax.ShapeDtypeStruct((N, T, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(out_x)
 
@@ -151,7 +151,7 @@ def duplex_kv_stream(in_q, in_scale, out_x, *, interpret: bool = False,
             f"duplex stream length {N} is not a multiple of the staging "
             f"depth {stage_blocks}; pad the streams")
     s = _specs(N, T, D, stage=stage_blocks)
-    dim_sem = CompilerParams(dimension_semantics=("arbitrary",))
+    dim_sem = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
     if fused:
         return pl.pallas_call(

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -137,7 +135,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
             pltpu.VMEM((qb,), jnp.float32),      # l
             pltpu.VMEM((qb, hd), jnp.float32),   # acc
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

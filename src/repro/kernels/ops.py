@@ -1,8 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the wrappers default to ``interpret=True`` so the
-kernel bodies execute in Python for correctness validation; on TPU they
-compile natively. The pure-jnp oracles live in ``ref.py``.
+On the CPU backend the wrappers default to ``interpret=True`` so the
+kernel bodies execute in Python for correctness validation; on any other
+backend they compile natively (a kernel that cannot compile there fails
+loudly instead of silently interpreting). The pure-jnp oracles live in
+``ref.py``.
 """
 
 from __future__ import annotations
@@ -16,7 +18,7 @@ from repro.kernels import vector_distance as _vd
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, prefix_len=0,

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref, *,
                 chunk: int):
@@ -74,7 +72,7 @@ def wkv6(r, k, v, w, u, *, chunk: int = 128, interpret: bool = False):
         out_specs=seq_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, S, hs), jnp.float32),
         scratch_shapes=[pltpu.VMEM((hs, hs), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rf, kf, vf, wf, u.astype(jnp.float32))

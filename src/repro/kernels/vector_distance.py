@@ -23,8 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _l2_kernel(q_ref, blk_ref, out_ref):
@@ -56,6 +55,7 @@ def l2_distance(queries, blocks, *, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((1, Q, T), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((N, Q, T), jnp.float32),
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(queries, blocks)

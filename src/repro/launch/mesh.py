@@ -14,16 +14,9 @@ import jax
 
 
 def abstract_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Device-free AbstractMesh across jax versions.
-
-    jax >= 0.5 takes ``(shape, axis_names)``; older releases take one
-    ``((name, size), ...)`` tuple.
-    """
+    """A device-free ``AbstractMesh`` of ``shape`` over ``axes``."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(shape, axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -28,6 +28,7 @@ import numpy as np
 from repro import configs as configs_lib
 from repro.core import channel as channel_lib
 from repro.core import faults as faults_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry as R
 from repro.serve import (EngineConfig, EngineStallError, KVStoreTenant,
                          ServeEngine, VectorSearchTenant)
@@ -195,6 +196,7 @@ def main() -> int:
                    help="also run the legacy synthetic tiered-KV demo")
     args = p.parse_args()
 
+    enable_compile_cache()
     api = R.build(args.arch, smoke=not args.full)
     params = api.init(jax.random.PRNGKey(0))
     # tenants reserve per-step HBM headroom; grow the pool's working set
@@ -453,8 +455,11 @@ def main() -> int:
             return {k: _round(x) for k, x in v.items()}
         return v
 
+    dev0 = jax.devices()[0]
     report = {
         "arch": args.arch,
+        "device": {"platform": dev0.platform, "kind": dev0.device_kind,
+                   "count": len(jax.devices())},
         "policy": args.policy,
         "requests": args.requests,
         "tenants": tenant_names,

@@ -156,12 +156,17 @@ class PagedKVPool:
     minor direction of the CXL links. ``tiers=None`` is the flat
     single-channel pool with identity placement — the pre-tiered layout
     and billing, bit-for-bit.
+
+    ``device`` commits the pool's buffers (``hbm``, ``host_q``,
+    ``host_scale``) to one device, so every paging program and kernel
+    runs there; ``None`` leaves them on the default device.
     """
 
     def __init__(self, n_blocks: int, hbm_blocks: int, block_shape,
                  hints: HintTree | None = None,
                  link: channel_lib.ChannelModel = channel_lib.PCIE_HOST,
-                 tiers=None, migrate_max: int = 8, faults=None):
+                 tiers=None, migrate_max: int = 8, faults=None,
+                 device=None):
         if hbm_blocks < 1:
             raise ValueError("need at least one HBM block")
         self.n_blocks = n_blocks
@@ -175,12 +180,14 @@ class PagedKVPool:
                                                  block_bytes)
         self.tiered = self.host.tiered
         self.migrate_max = int(migrate_max)
-        self.hbm = jnp.zeros((hbm_blocks,) + self.block_shape, jnp.bfloat16)
+        self.device = device
+        self.hbm = jnp.zeros((hbm_blocks,) + self.block_shape, jnp.bfloat16,
+                             device=device)
         self.host_q = jnp.zeros((self.host.total_slots,) + self.block_shape,
-                                jnp.int8)
+                                jnp.int8, device=device)
         self.host_scale = jnp.ones((self.host.total_slots,
                                     self.block_shape[0], 1),
-                                   jnp.float32)
+                                   jnp.float32, device=device)
         # block table (host-resident residency metadata — never feeds
         # device compute, so it lives in numpy):
         self.slot_of = np.full((n_blocks,), -1, np.int32)    # block -> slot
@@ -934,9 +941,11 @@ class PagedKVPool:
                 f"pool snapshot HBM shape {hbm.shape} does not match "
                 f"this pool ({(self.hbm_capacity,) + self.block_shape})"
                 " — restore needs the crashed run's pool config")
-        self.hbm = jnp.asarray(hbm, jnp.bfloat16)
-        self.host_q = jnp.asarray(state["host_q"], jnp.int8)
-        self.host_scale = jnp.asarray(state["host_scale"], jnp.float32)
+        self.hbm = jnp.asarray(hbm, jnp.bfloat16, device=self.device)
+        self.host_q = jnp.asarray(state["host_q"], jnp.int8,
+                                  device=self.device)
+        self.host_scale = jnp.asarray(state["host_scale"], jnp.float32,
+                                      device=self.device)
         self.slot_of = np.asarray(state["slot_of"], np.int32).copy()
         self.block_at = np.asarray(state["block_at"], np.int32).copy()
         self.last_use = np.asarray(state["last_use"], np.int64).copy()

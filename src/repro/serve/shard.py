@@ -37,11 +37,13 @@ Cross-device traffic accounting (``IciMeter``) lands in
 channels use — per-link accounting composes at scale only if every
 link flows through the same model.
 
-The sync budget is unchanged: ONE packed readback per megastep *per
-mesh* (not per device) — ``np.asarray`` on the mesh-sharded packed
-array is the single deferred device->host sync; the staged
-write-through slab lands on the pool device as a device-to-device copy
-that never touches the host.
+Each data rank's pool shard lives on that rank's device (the first
+device of its row in the mesh), next to the cache rows it mirrors: the
+rank's band of the staged write-through slab is already there, so
+write-through moves no bytes between devices. The sync budget is
+unchanged: ONE packed readback per megastep *per mesh* (not per device)
+— ``np.asarray`` on the mesh-sharded packed array is the single
+deferred device->host sync.
 """
 
 from __future__ import annotations
@@ -60,29 +62,6 @@ from repro.core.hints import HintTree
 from repro.serve.engine import ServeEngine, _megastep_math
 from repro.serve.kv_pool import PagedKVPool
 from repro.serve.queue import Request, S_DONE, S_PREFILL
-
-try:  # jax >= 0.4.35 keeps shard_map under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:  # pragma: no cover - newer jax promoted it
-    from jax import shard_map as _shard_map
-
-
-def _compat_shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` without replication checking, across jax versions
-    (``check_rep`` was renamed ``check_vma``). The model-axis compute is
-    replicated by construction (identical math on identical inputs), but
-    the checker cannot track that through the engine's scan/cond
-    structure for arbitrary ``decode_step`` bodies — so it is off, and
-    the differential test lane is the guarantee instead."""
-    for kw in ("check_rep", "check_vma"):
-        try:
-            return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **{kw: False})
-        except TypeError:
-            continue
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs)
-
 
 @functools.lru_cache(maxsize=64)
 def _sharded_megastep_program(api, n_micro: int, n_steps: int,
@@ -115,9 +94,14 @@ def _sharded_megastep_program(api, n_micro: int, n_steps: int,
     dev_spec = P("data")                  # every dev leaf is (B, ...)
     out_specs = ((cache_spec, dev_spec, P("data"), P(None, "data"))
                  if extract else (cache_spec, dev_spec, P("data")))
-    fn = _compat_shard_map(sharded, mesh,
-                           in_specs=(P(), cache_spec, dev_spec),
-                           out_specs=out_specs)
+    # replication checking is off: the model-axis compute is replicated
+    # by construction (identical math on identical inputs), but the
+    # checker cannot track that through the engine's scan/cond structure
+    # for arbitrary ``decode_step`` bodies — the differential test lane
+    # is the guarantee instead.
+    fn = jax.shard_map(sharded, mesh=mesh,
+                       in_specs=(P(), cache_spec, dev_spec),
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn, donate_argnums=(1, 2))
 
 
@@ -318,13 +302,19 @@ class ShardedKVPool:
     Non-LLM tenants pin to shard 0 (their ``alloc`` default): shard 0's
     global ids coincide with its local ids, so the tenant-facing
     ``slot_of``/``hbm`` views stay valid unchanged.
+
+    ``devices`` (one per shard) places shard ``s``'s buffers on
+    ``devices[s]``; ``None`` keeps every shard on the default device.
     """
 
     def __init__(self, n_shards: int, n_blocks: int, hbm_blocks: int,
                  block_shape, hints: HintTree | None = None,
-                 tiers=None, migrate_max: int = 8, faults=None):
+                 tiers=None, migrate_max: int = 8, faults=None,
+                 devices=None):
         if n_shards < 1:
             raise ValueError("need at least one pool shard")
+        if devices is None:
+            devices = [None] * n_shards
         self.n_shards = n_shards
         self.blocks_per_shard = n_blocks
         self.n_blocks = n_shards * n_blocks          # global id space
@@ -340,7 +330,7 @@ class ShardedKVPool:
         self.shards = [
             PagedKVPool(n_blocks, hbm_blocks, block_shape, hints=hints,
                         tiers=tiers, migrate_max=migrate_max,
-                        faults=shard_faults[s])
+                        faults=shard_faults[s], device=devices[s])
             for s in range(n_shards)]
         self.host = _ShardedHostView(self.shards)
         self.tiered = self.shards[0].tiered
@@ -475,17 +465,18 @@ class ShardedKVPool:
                 sh.write(ids, data)
 
     def write_staged(self, blocks, staged, step: int) -> None:
-        """Split the megastep staging slab by slot ownership: ids are
-        slot-major (``slot * max_fills + j``) over the global batch, so
-        shard ``s`` owns the contiguous row band of its slots."""
+        """Write-through by slot ownership: ids are slot-major
+        (``slot * max_fills + j``) over the global batch, so shard ``s``
+        owns the contiguous row band of its slots, and ``staged[s]`` is
+        that band of the megastep staging slab (K, rows, tokens,
+        kv_dims), already on shard ``s``'s device."""
         blocks = np.asarray(blocks, np.int32).reshape(-1)
         rows = blocks.size // self.n_shards
         for s, sh in enumerate(self.shards):
             band = blocks[s * rows:(s + 1) * rows]
             ids = self._localize_write_ids(band, s)
             if (ids < self.blocks_per_shard).any():
-                sh.write_staged(ids, staged[:, s * rows:(s + 1) * rows],
-                                step)
+                sh.write_staged(ids, staged[s], step)
 
     def read(self, blocks):
         blocks = np.asarray(blocks, np.int32).reshape(-1)
@@ -496,7 +487,10 @@ class ShardedKVPool:
             idx = np.flatnonzero(
                 (blocks >= lo) & (blocks < lo + self.blocks_per_shard))
             if idx.size:
-                parts.append(sh.read(blocks[idx] - lo))
+                # gathered onto shard 0's device: concatenation needs
+                # one device
+                parts.append(jax.device_put(sh.read(blocks[idx] - lo),
+                                            self.shards[0].device))
                 order.append(idx)
         if not parts:
             raise ValueError("read of no blocks")
@@ -655,10 +649,12 @@ class ShardedServeEngine(ServeEngine):
     * the megastep cell is the ``shard_map``-wrapped program;
     * params/cache/slot-state live on the mesh (params replicated,
       batch-dim leaves split over ``data``);
-    * the KV pool is a ``ShardedKVPool`` (one shard per data rank) and
-      block allocation routes by the owning slot's shard;
-    * the staged write-through slab lands on the pool device as a d2d
-      copy (``_stage_view``) — still zero host syncs mid-megastep;
+    * the KV pool is a ``ShardedKVPool`` (one shard per data rank, on
+      that rank's device) and block allocation routes by the owning
+      slot's shard;
+    * the staged write-through slab is split into its per-rank bands
+      where they already live (``_stage_view``) — no copy, and still
+      zero host syncs mid-megastep;
     * modelled ICI traffic for the megastep's collectives is billed at
       dispatch (``IciMeter``) and surfaces in ``paging_stats()``.
     """
@@ -678,13 +674,16 @@ class ShardedServeEngine(ServeEngine):
                 f"fixed slot band")
         self.slots_per_shard = cfg.max_batch // self.data_size
         self._ici = IciMeter(mesh)
+        # data rank s's pool shard lives on the first device of mesh row
+        # s, which holds that rank's cache rows and staged band.
+        self.pool_devices = [mesh.devices[s, 0]
+                             for s in range(self.data_size)]
         super().__init__(api, params, cfg, hints)
         # the base __init__ built the tracer/CAX registry; the ICI links
         # join the same modelled clock and scope tree.
         self._ici.trace = self._tracer
         self._ici.telemetry = self.telemetry
         self._place_device_state()
-        self._pool_device = next(iter(jax.devices()))
         # per-layer tensor-parallel psum payload (bf16 activations): the
         # launch.sharding row-parallel rules (attn/wo and mlp/w_down
         # sharded on the contraction dim) imply one all-reduce each.
@@ -699,9 +698,8 @@ class ShardedServeEngine(ServeEngine):
     def _place_device_state(self) -> None:
         """Land the device state on the mesh: params replicated, cache
         leaves (L, B, ...) and slot-state leaves (B, ...) split over
-        the data axis. The pool's own buffers stay on the default
-        device (its kernels are per-shard host-modelled programs).
-        Called at construction AND after a snapshot restore reloads
+        the data axis. The pool shards place their own buffers
+        (``pool_devices``). Called at construction AND after a snapshot restore reloads
         ``cache``/``_dev`` as host arrays — the placement seam the
         restore path re-runs."""
         mesh = self.mesh
@@ -720,7 +718,8 @@ class ShardedServeEngine(ServeEngine):
         return ShardedKVPool(
             self.data_size, self.cfg.resolved_pool_blocks(),
             self.cfg.hbm_blocks, block_shape, hints=self.hints,
-            tiers=self.cfg.tiers, faults=self.cfg.faults)
+            tiers=self.cfg.tiers, faults=self.cfg.faults,
+            devices=self.pool_devices)
 
     def _alloc_block(self, r: Request) -> list[int]:
         return self.pool.alloc(1, shard=r.slot // self.slots_per_shard)
@@ -731,10 +730,10 @@ class ShardedServeEngine(ServeEngine):
             self.api, self.cfg.prefill_chunk, n_steps, bt, self.mesh)
 
     def _stage_view(self, staged):
-        # mesh-sharded (K, B*max_fills, bt, kv) slab -> the pool device.
-        # Device-to-device: the megastep's one deferred d2h sync is still
-        # the packed readback alone.
-        return jax.device_put(staged, self._pool_device)
+        # the mesh-sharded (K, B*max_fills, bt, kv) slab, as each data
+        # rank's band on its pool shard's device — no copy.
+        band = {sh.device: sh.data for sh in staged.addressable_shards}
+        return [band[d] for d in self.pool_devices]
 
     # -- ICI accounting ------------------------------------------------------
     def _dispatch(self, rec):
@@ -748,8 +747,8 @@ class ShardedServeEngine(ServeEngine):
         step, the tensor-parallel psums the PartitionSpec rules imply
         (skipped when the step's ``lax.cond`` skipped the model — no
         movers, no collective) on the model axis; per megastep, the real
-        packed-readback ``pmax`` (model axis) and the staged-slab
-        gather onto the pool device (data axis)."""
+        packed-readback ``pmax`` (model axis) and the packed readback's
+        gather across the mesh (data axis)."""
         n_micro = max(1, self.cfg.prefill_chunk)
         if self.model_size > 1:
             for t in range(rec.k):
@@ -773,18 +772,10 @@ class ShardedServeEngine(ServeEngine):
                 "model",
                 float(self.slots_per_shard * (3 + rec.k) * 4))
         if self.data_size > 1:
-            # packed readback crosses the mesh once per megastep...
+            # packed readback crosses the mesh once per megastep (the
+            # staged slab stays on its rank's device: nothing to bill).
             self._ici.note_allgather(
                 "data", float(self.slots_per_shard * (3 + rec.k) * 4))
-            if self.paged:
-                # ...and the staged slab's foreign rows ride ICI to the
-                # pool device (the _stage_view d2d copy).
-                bt = self.cfg.block_tokens
-                max_fills = -(-n_micro // bt)
-                kv_dims = self.pool.block_shape[1]
-                shard_bytes = (rec.k * self.slots_per_shard * max_fills
-                               * bt * kv_dims * 2)
-                self._ici.note_allgather("data", float(shard_bytes))
 
     # -- snapshot seams ------------------------------------------------------
     def _snapshot_extra_state(self) -> dict:

@@ -234,6 +234,28 @@ class TestShardDifferential:
         assert not eng.pending()
         assert saw_blocks
 
+    @pytest.mark.parametrize("dm", [(4, 1), (2, 2)])
+    def test_pool_shard_lives_on_its_rank_device(self, api, params, dm):
+        """Shard s's pool buffers sit on data rank s's device (the first
+        device of mesh row s), before and after serving — paging and
+        write-through never pull them onto another device."""
+        mesh = _mesh(*dm)
+        # 7 filled blocks per request, more than a shard's 6 HBM
+        # slots even at one slot per shard: every shard pages.
+        ref = _drive(api, ServeEngine(api, params, _cfg()), gen=24)
+        eng = ShardedServeEngine(api, params, _cfg(), mesh=mesh)
+
+        def check():
+            for s, sh in enumerate(eng.pool.shards):
+                want = {mesh.devices[s, 0]}
+                for buf in (sh.hbm, sh.host_q, sh.host_scale):
+                    assert buf.devices() == want, (s, buf.devices())
+
+        check()
+        _assert_differential(_drive(api, eng, gen=24), ref)
+        assert all(sh.stats["page_outs"] > 0 for sh in eng.pool.shards)
+        check()
+
     def test_uneven_batch_rejected(self, api, params):
         mesh = _mesh(2, 1)
         with pytest.raises(ValueError, match="data axis"):
