@@ -27,4 +27,4 @@ from repro.core.requests import StreamSpec, generate, redis_pattern_specs
 from repro.core.scheduler import (
     SimConfig, SimResult, simulate, compare_policies, improvement,
 )
-from repro.core.telemetry import CaxRegistry, CaxContext, global_registry
+from repro.core.telemetry import CaxRegistry, CaxContext

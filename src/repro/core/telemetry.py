@@ -190,16 +190,3 @@ class CaxRegistry:
             c.samples = 0
             c.last_update = 0.0
 
-
-# A process-wide default registry, like the kernel's single BPF map.
-_GLOBAL = CaxRegistry()
-
-
-def global_registry() -> CaxRegistry:
-    return _GLOBAL
-
-
-def reset_global_registry() -> CaxRegistry:
-    global _GLOBAL
-    _GLOBAL = CaxRegistry()
-    return _GLOBAL

@@ -114,6 +114,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import time
 
 import jax
 import jax.numpy as jnp
@@ -131,7 +132,7 @@ from repro.serve.queue import (DECODE, DONE, FAILED, PREFILL,
                                STATE_OF_CODE, Request, RequestQueue,
                                S_DECODE, S_DONE, S_EMPTY, S_PREFILL)
 from repro.serve.snapshot import SnapshotManager, fresh_snapshot_stats
-from repro.serve.trace import Tracer
+from repro.serve.trace import Tracer, phase
 
 
 class EngineStallError(RuntimeError):
@@ -399,18 +400,20 @@ def _megastep_math(api: ModelAPI, n_micro: int, n_steps: int,
                     # every non-mover row's pre-step leaves. Ring caches
                     # skip this — the dummy entry is overwritten before
                     # it is ever attended.
-                    new_cache = jax.tree.map(
-                        lambda new, old: jnp.where(
-                            _row_mask(movers, new), new, old),
-                        new_cache, c)
+                    with jax.named_scope("megastep/row_keep"):
+                        new_cache = jax.tree.map(
+                            lambda new, old: jnp.where(
+                                _row_mask(movers, new), new, old),
+                            new_cache, c)
                 picked = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return new_cache, picked
 
             # a micro-step with no movers (every live row already decoded
             # this step) skips the model entirely.
-            cache, picked = lax.cond(
-                movers.any(), advance,
-                lambda c: (c, jnp.zeros((B,), jnp.int32)), cache)
+            with jax.named_scope("megastep/model"):
+                cache, picked = lax.cond(
+                    movers.any(), advance,
+                    lambda c: (c, jnp.zeros((B,), jnp.int32)), cache)
 
             pref_mover = movers & prefilling
             consumed = dev["consumed"] + pref_mover.astype(jnp.int32)
@@ -448,17 +451,19 @@ def _megastep_math(api: ModelAPI, n_micro: int, n_steps: int,
             t0 = (jnp.repeat(fill_base, max_fills)
                   + jnp.tile(jnp.arange(max_fills, dtype=jnp.int32),
                              B)) * block_tokens
-            staged = _extract_blocks_math(cache["k"], cache["v"],
-                                          slot_idx, t0,
-                                          block_tokens=block_tokens)
+            with jax.named_scope("megastep/writethrough"):
+                staged = _extract_blocks_math(cache["k"], cache["v"],
+                                              slot_idx, t0,
+                                              block_tokens=block_tokens)
             return (cache, dev), (dev["tok"], staged)
 
         (cache, dev), ys = lax.scan(inner, (cache, dev), None,
                                     length=n_steps)
         toks = ys[0] if extract else ys          # (K, B)
-        packed = jnp.concatenate(
-            [dev["state"][:, None], dev["consumed"][:, None],
-             dev["n_gen"][:, None], jnp.swapaxes(toks, 0, 1)], axis=1)
+        with jax.named_scope("megastep/pack"):
+            packed = jnp.concatenate(
+                [dev["state"][:, None], dev["consumed"][:, None],
+                 dev["n_gen"][:, None], jnp.swapaxes(toks, 0, 1)], axis=1)
         if extract:
             return cache, dev, packed, ys[1]
         return cache, dev, packed
@@ -696,6 +701,7 @@ class ServeEngine:
                     f"step but the pool holds {self.cfg.hbm_blocks} HBM "
                     f"blocks; grow hbm_blocks or shrink prefill_chunk/"
                     f"block_tokens")
+        req.t_submit = time.perf_counter()
         self.queue.submit(req)
         if self._snap is not None:
             self._snap.note_submit(self, req)
@@ -751,15 +757,14 @@ class ServeEngine:
         view of the request mirrors (``Request.plan_*``: identical to
         the real mirrors at depth 1, one dispatched-but-unreconciled
         boundary ahead of them at depth 2). No device sync."""
-        t0 = self._tracer.now_us() if self._tracer is not None else 0.0
         k = int(n_steps) if n_steps else max(1, self.cfg.megastep)
         now = self.step_count
-        admitted = self._admit(now)
-        live = self.active()
-        traj = {r.rid: self._simulate_row(r, k) for r in live}
-        if self._tracer is not None:
-            self._tracer.span("plan", t0, step=now, k=k,
-                              admitted=admitted, live=len(live))
+        with phase(self._tracer, "plan", step=now, k=k) as span:
+            with phase(self._tracer, "admit", step=now):
+                admitted = self._admit(now)
+            live = self.active()
+            traj = {r.rid: self._simulate_row(r, k) for r in live}
+            span.update(admitted=admitted, live=len(live))
         return _InFlight(now=now, k=k, admitted=admitted, live=live,
                          traj=traj)
 
@@ -773,127 +778,127 @@ class ServeEngine:
         (speculative mirrors, trajectory-driven retirement, step
         counters), and every pool alloc/free is journaled on ``rec`` so
         a later divergence can roll it back."""
-        t0 = self._tracer.now_us() if self._tracer is not None else 0.0
         now, k, live, traj = rec.now, rec.k, rec.live, rec.traj
-        staged = None
-        if live:
-            out = self._mega_fn(k)(self.params, self.cache, self._dev)
-            if self.paged:
-                self.cache, self._dev, rec.packed, staged = out
-                staged = self._stage_view(staged)
-            else:
-                self.cache, self._dev, rec.packed = out
-            self.host_dispatches += 1
+        with phase(self._tracer, "dispatch", step=now, k=k,
+                   live=len(live)) as span:
+            staged = None
+            if live:
+                out = self._mega_fn(k)(self.params, self.cache, self._dev)
+                if self.paged:
+                    self.cache, self._dev, rec.packed, staged = out
+                    staged = self._stage_view(staged)
+                else:
+                    self.cache, self._dev, rec.packed = out
+                self.host_dispatches += 1
 
-        report = {"page_ins": 0, "page_outs": 0, "migrations": 0}
-        feedbacks = []
-        tenant_done = 0
-        for t in range(k):
-            rows = []
+            report = {"page_ins": 0, "page_outs": 0, "migrations": 0}
+            feedbacks = []
+            tenant_done = 0
+            for t in range(k):
+                rows = []
+                for r in live:
+                    if r.state == FAILED:
+                        continue
+                    st = traj[r.rid][t]
+                    if st.state != S_DONE:
+                        rows.append((r, st))
+                if self.paged:
+                    rep = self._page_kv_at(now + t, rows, staged, t,
+                                           rec.journal)
+                    report["page_ins"] += rep["page_ins"]
+                    report["page_outs"] += rep["page_outs"]
+                    if self._fx is not None:
+                        self._service_fault_report(rep, now + t, rec)
+                    # rows completing at this inner step release their pool
+                    # blocks NOW (deterministic), exactly when the per-step
+                    # loop would have — holding them to the boundary would
+                    # force spurious evictions on later inner steps.
+                    for r in live:
+                        st = traj[r.rid][t]
+                        if (st.state == S_DONE and r.blocks
+                                and not r.blocks_freed
+                                and (t == 0
+                                     or traj[r.rid][t - 1].state != S_DONE)):
+                            self.pool.free(r.blocks)
+                            r.blocks_freed = True
+                            rec.journal.append(("free", r, list(r.blocks)))
+                for tn in self.tenants.values():
+                    for r in tn.retire(now + t):
+                        self.completed[r.rid] = r
+                        tenant_done += 1
+                if k > 1:
+                    feedbacks.append(policies_lib.Feedback(
+                        moved_read=self._fb_zero,
+                        moved_write=self._fb_zero,
+                        utilization=np.float32(
+                            len(rows) / max(1, self.cfg.max_batch))))
+
+            if self.paged and self.pool.tiered and self.cfg.tier_migrate:
+                # boundary tier rebalance: planned from this megastep's
+                # per-channel traffic window (host metadata only), executed
+                # as one dispatched row copy riding the CXL links' idle
+                # minor direction — before the readback is ever consumed, so
+                # the move overlaps the still-in-flight compute. Plans may
+                # cover planned-not-yet-reconciled residency; that is safe —
+                # moves relocate verbatim host bytes and a divergence
+                # rollback only needs ownership consistency, not placement
+                # restoration.
+                report["migrations"] = self.pool.migrate_tiers()["migrations"]
+
+            if self._fx is not None and self.paged \
+                    and self.pool.host.capacity_degraded:
+                self._shed_over_capacity(rec)
+
+            # the megastep's outcome — bar token values — is already decided,
+            # so the planning view advances NOW: speculative mirrors jump to
+            # the trajectory's final step and predicted-DONE rows leave their
+            # slots (trajectory-driven retirement), letting the next _plan()
+            # admit into the post-megastep batch before this readback lands.
             for r in live:
                 if r.state == FAILED:
                     continue
-                st = traj[r.rid][t]
-                if st.state != S_DONE:
-                    rows.append((r, st))
-            if self.paged:
-                rep = self._page_kv_at(now + t, rows, staged, t,
-                                       rec.journal)
-                report["page_ins"] += rep["page_ins"]
-                report["page_outs"] += rep["page_outs"]
-                if self._fx is not None:
-                    self._service_fault_report(rep, now + t, rec)
-                # rows completing at this inner step release their pool
-                # blocks NOW (deterministic), exactly when the per-step
-                # loop would have — holding them to the boundary would
-                # force spurious evictions on later inner steps.
-                for r in live:
-                    st = traj[r.rid][t]
-                    if (st.state == S_DONE and r.blocks
-                            and not r.blocks_freed
-                            and (t == 0
-                                 or traj[r.rid][t - 1].state != S_DONE)):
-                        self.pool.free(r.blocks)
-                        r.blocks_freed = True
-                        rec.journal.append(("free", r, list(r.blocks)))
-            for tn in self.tenants.values():
-                for r in tn.retire(now + t):
-                    self.completed[r.rid] = r
-                    tenant_done += 1
-            if k > 1:
-                feedbacks.append(policies_lib.Feedback(
-                    moved_read=self._fb_zero,
-                    moved_write=self._fb_zero,
-                    utilization=np.float32(
-                        len(rows) / max(1, self.cfg.max_batch))))
+                last = traj[r.rid][-1]
+                r.speculate(STATE_OF_CODE[last.state], last.consumed,
+                            last.n_gen)
+            report["completed"] = tenant_done + self._retire_planned(rec)
+            rec.report = report
 
-        if self.paged and self.pool.tiered and self.cfg.tier_migrate:
-            # boundary tier rebalance: planned from this megastep's
-            # per-channel traffic window (host metadata only), executed
-            # as one dispatched row copy riding the CXL links' idle
-            # minor direction — before the readback is ever consumed, so
-            # the move overlaps the still-in-flight compute. Plans may
-            # cover planned-not-yet-reconciled residency; that is safe —
-            # moves relocate verbatim host bytes and a divergence
-            # rollback only needs ownership consistency, not placement
-            # restoration.
-            report["migrations"] = self.pool.migrate_tiers()["migrations"]
-
-        if self._fx is not None and self.paged \
-                and self.pool.host.capacity_degraded:
-            self._shed_over_capacity(rec)
-
-        # the megastep's outcome — bar token values — is already decided,
-        # so the planning view advances NOW: speculative mirrors jump to
-        # the trajectory's final step and predicted-DONE rows leave their
-        # slots (trajectory-driven retirement), letting the next _plan()
-        # admit into the post-megastep batch before this readback lands.
-        for r in live:
-            if r.state == FAILED:
-                continue
-            last = traj[r.rid][-1]
-            r.speculate(STATE_OF_CODE[last.state], last.consumed,
-                        last.n_gen)
-        report["completed"] = tenant_done + self._retire_planned(rec)
-        rec.report = report
-
-        if feedbacks and len(self.queue):
-            # megastep-boundary policy feedback: K per-step Feedbacks
-            # folded through Policy.update as one scanned program, and
-            # the megastep's mean slot utilization surfaced to the next
-            # schedule() as Obs.prev_util (host float — no device sync;
-            # this is what the oversubscription detector reads). The
-            # engine has no per-waiting-slot service to report, so for
-            # the registered policies the fold itself is state-invariant
-            # (zero moved bytes) — it is the boundary *contract*: a
-            # policy whose update reads utilization or cross-step
-            # structure gets the full per-step sequence, not a lossy
-            # sum. One small dispatch per boundary buys that. Only
-            # worth dispatching while requests wait — with an empty
-            # waiting room there is no admission ranking to influence.
-            # Padded up to the configured megastep width so the fold
-            # compiles once per engine config, not once per adaptive
-            # gap length (a zero-service step is an update no-op for
-            # every registered policy); an explicit megastep() call
-            # wider than the config gets its own cell.
-            util = float(np.mean([float(fb.utilization)
-                                  for fb in feedbacks]))
-            zero = policies_lib.Feedback(
-                moved_read=self._fb_zero, moved_write=self._fb_zero,
-                utilization=np.float32(0.0))
-            pad = max(0, max(1, self.cfg.megastep) - len(feedbacks))
-            self.queue.note_service(
-                policies_lib.stack_feedbacks(feedbacks + [zero] * pad),
-                mean_util=util)
-        self.step_count += k
-        self.megasteps += 1
-        self._inflight.append(rec)
+            if feedbacks and len(self.queue):
+                # megastep-boundary policy feedback: K per-step Feedbacks
+                # folded through Policy.update as one scanned program, and
+                # the megastep's mean slot utilization surfaced to the next
+                # schedule() as Obs.prev_util (host float — no device sync;
+                # this is what the oversubscription detector reads). The
+                # engine has no per-waiting-slot service to report, so for
+                # the registered policies the fold itself is state-invariant
+                # (zero moved bytes) — it is the boundary *contract*: a
+                # policy whose update reads utilization or cross-step
+                # structure gets the full per-step sequence, not a lossy
+                # sum. One small dispatch per boundary buys that. Only
+                # worth dispatching while requests wait — with an empty
+                # waiting room there is no admission ranking to influence.
+                # Padded up to the configured megastep width so the fold
+                # compiles once per engine config, not once per adaptive
+                # gap length (a zero-service step is an update no-op for
+                # every registered policy); an explicit megastep() call
+                # wider than the config gets its own cell.
+                util = float(np.mean([float(fb.utilization)
+                                      for fb in feedbacks]))
+                zero = policies_lib.Feedback(
+                    moved_read=self._fb_zero, moved_write=self._fb_zero,
+                    utilization=np.float32(0.0))
+                pad = max(0, max(1, self.cfg.megastep) - len(feedbacks))
+                self.queue.note_service(
+                    policies_lib.stack_feedbacks(feedbacks + [zero] * pad),
+                    mean_util=util)
+            self.step_count += k
+            self.megasteps += 1
+            self._inflight.append(rec)
+            span.update(in_flight=len(self._inflight),
+                        page_ins=report["page_ins"],
+                        page_outs=report["page_outs"],
+                        migrations=report["migrations"])
         if self._tracer is not None:
-            self._tracer.span(
-                "dispatch", t0, step=now, k=k, live=len(live),
-                in_flight=len(self._inflight),
-                page_ins=report["page_ins"], page_outs=report["page_outs"],
-                migrations=report["migrations"])
             self._tracer.counter("in_flight", len(self._inflight))
         return rec
 
@@ -932,75 +937,88 @@ class ServeEngine:
         at depth 2 it runs one boundary late, with t+1 already in
         flight. A readback that contradicts its trajectory rolls back
         every speculative pool mutation before raising."""
-        t0 = self._tracer.now_us() if self._tracer is not None else 0.0
-        self._inflight.remove(rec)
-        bubble = bool(rec.live and not self._inflight)
-        if bubble:
-            # the host blocks on this readback with nothing dispatched
-            # ahead of it — a pipeline bubble.
-            self.host_blocked += 1
-        advanced = 0
-        tok_pairs = [] if self._snap is not None else None
-        if rec.live:
-            rb = self._readback(rec.packed)
-            try:
-                for r in rec.live:
-                    if r.state == FAILED:
-                        # failed mid-flight (poison/casualty/shed): the
-                        # device row's readback is moot — the request
-                        # already carries its structured error.
-                        continue
-                    steps_r = rec.traj[r.rid]
-                    toks = [int(rb[r.slot, 3 + t])
-                            for t, st in enumerate(steps_r) if st.emitted]
-                    c0, g0 = r.consumed, len(r.generated)
-                    dev_state = int(rb[r.slot, 0])
-                    dev_consumed = int(rb[r.slot, 1])
-                    dev_ngen = int(rb[r.slot, 2])
-                    last = steps_r[-1]
-                    exp_ngen = g0 + sum(st.emitted for st in steps_r)
-                    fields = []
-                    if STATE_OF_CODE.get(dev_state) != \
-                            STATE_OF_CODE[last.state]:
-                        fields.append(
-                            f"state (host planned "
-                            f"{STATE_OF_CODE[last.state]}, device "
-                            f"reported {STATE_OF_CODE.get(dev_state, f'code {dev_state}')})")
-                    if dev_consumed != last.consumed:
-                        fields.append(
-                            f"consumed (host planned {last.consumed}, "
-                            f"device reported {dev_consumed})")
-                    if dev_ngen != exp_ngen:
-                        fields.append(
-                            f"n_gen (host planned {exp_ngen}, device "
-                            f"reported {dev_ngen})")
-                    if fields:
-                        raise RuntimeError(
-                            f"rid {r.rid}: boundary at step {rec.now} "
-                            f"(k={rec.k}): device readback diverged "
-                            f"from the host trajectory on "
-                            + "; ".join(fields))
-                    r.sync_megastep(dev_state, dev_consumed,
-                                    dev_ngen, toks)
-                    advanced += ((last.consumed + last.n_gen) - (c0 + g0)
-                                 - sum(st.transition for st in steps_r))
-                    if tok_pairs is not None and toks:
-                        tok_pairs.append((r.rid, toks))
-            except RuntimeError:
-                if self._tracer is not None:
-                    self._tracer.instant(
-                        "engine", "divergence_rollback",
-                        {"step": rec.now, "k": rec.k}, clock="host")
-                self._rollback_speculation(rec)
-                raise
-        if self._snap is not None:
-            self._snap.note_boundary(
-                self, rec.now, rec.k,
-                [r.rid for r in rec.live
-                 if r.admitted_step == rec.now], tok_pairs)
-        if self._tracer is not None:
-            self._tracer.span("reconcile", t0, step=rec.now, k=rec.k,
-                              host_blocked=bubble, advanced=advanced)
+        with phase(self._tracer, "reconcile", step=rec.now,
+                   k=rec.k) as span:
+            self._inflight.remove(rec)
+            bubble = bool(rec.live and not self._inflight)
+            if bubble:
+                # the host blocks on this readback with nothing dispatched
+                # ahead of it — a pipeline bubble.
+                self.host_blocked += 1
+            advanced = 0
+            tok_pairs = [] if self._snap is not None else None
+            firsts, lasts = [], []   # rows given their first/last token
+            if rec.live:
+                with phase(self._tracer, "readback", step=rec.now):
+                    rb = self._readback(rec.packed)
+                try:
+                    for r in rec.live:
+                        if r.state == FAILED:
+                            # failed mid-flight (poison/casualty/shed): the
+                            # device row's readback is moot — the request
+                            # already carries its structured error.
+                            continue
+                        steps_r = rec.traj[r.rid]
+                        toks = [int(rb[r.slot, 3 + t])
+                                for t, st in enumerate(steps_r) if st.emitted]
+                        c0, g0 = r.consumed, len(r.generated)
+                        dev_state = int(rb[r.slot, 0])
+                        dev_consumed = int(rb[r.slot, 1])
+                        dev_ngen = int(rb[r.slot, 2])
+                        last = steps_r[-1]
+                        exp_ngen = g0 + sum(st.emitted for st in steps_r)
+                        fields = []
+                        if STATE_OF_CODE.get(dev_state) != \
+                                STATE_OF_CODE[last.state]:
+                            fields.append(
+                                f"state (host planned "
+                                f"{STATE_OF_CODE[last.state]}, device "
+                                f"reported {STATE_OF_CODE.get(dev_state, f'code {dev_state}')})")
+                        if dev_consumed != last.consumed:
+                            fields.append(
+                                f"consumed (host planned {last.consumed}, "
+                                f"device reported {dev_consumed})")
+                        if dev_ngen != exp_ngen:
+                            fields.append(
+                                f"n_gen (host planned {exp_ngen}, device "
+                                f"reported {dev_ngen})")
+                        if fields:
+                            raise RuntimeError(
+                                f"rid {r.rid}: boundary at step {rec.now} "
+                                f"(k={rec.k}): device readback diverged "
+                                f"from the host trajectory on "
+                                + "; ".join(fields))
+                        r.sync_megastep(dev_state, dev_consumed,
+                                        dev_ngen, toks)
+                        advanced += ((last.consumed + last.n_gen) - (c0 + g0)
+                                     - sum(st.transition for st in steps_r))
+                        if tok_pairs is not None and toks:
+                            tok_pairs.append((r.rid, toks))
+                        if toks and g0 == 0:
+                            firsts.append(r)
+                        if toks and r.finished:
+                            lasts.append(r)
+                except RuntimeError:
+                    if self._tracer is not None:
+                        self._tracer.instant(
+                            "engine", "divergence_rollback",
+                            {"step": rec.now, "k": rec.k}, clock="host")
+                    self._rollback_speculation(rec)
+                    raise
+            if self._snap is not None:
+                self._snap.note_boundary(
+                    self, rec.now, rec.k,
+                    [r.rid for r in rec.live
+                     if r.admitted_step == rec.now], tok_pairs)
+            span.update(host_blocked=bubble, advanced=advanced)
+        # one host-clock reading as the tokens are handed to the caller
+        t_host = time.perf_counter()
+        for r in firsts:
+            r.t_first = t_host
+        for r in lasts:
+            r.t_done = t_host
+            if self._tracer is not None:
+                self._tracer.request(r)
         return {"step": rec.now, "steps": rec.k,
                 "admitted": rec.admitted, "advanced": advanced,
                 **rec.report}
@@ -1381,6 +1399,9 @@ class ServeEngine:
         admitted = self.queue.dispatch(now, budget)
         if not admitted:
             return 0
+        t_admit = time.perf_counter()
+        for req in admitted:
+            req.t_admit = t_admit
         llm = [r for r in admitted if r.tenant == "llm"]
         for req in admitted:
             if req.tenant != "llm":
@@ -1631,11 +1652,12 @@ class ServeEngine:
         ``--telemetry`` and a future cluster router all read."""
         reg = MetricsRegistry()
         reg.ingest("engine", self.paging_stats())
+        if self._tracer is not None:
+            for name, _, dur, _ in self._tracer.spans:
+                reg.observe(f"span.{name}.us", dur)
         snap = reg.snapshot()
         if self._tracer is not None:
             snap["trace"] = self._tracer.summary()
-            snap["histograms"].update(
-                self._tracer.metrics.snapshot()["histograms"])
         snap["cax"] = self.telemetry.to_dict()
         return snap
 

@@ -127,6 +127,14 @@ class Request:
     #: optional completion deadline (engine step). Under degraded
     #: capacity the engine sheds doomed-deadline requests first.
     deadline_step: int | None = None
+    #: host-clock stamps (``time.perf_counter()`` seconds) of the
+    #: request's life in the engine: submitted, admitted to a slot,
+    #: first token value on the host, last token value on the host.
+    #: Wall-clock readings, so the snapshot leaves them out.
+    t_submit: float | None = None
+    t_admit: float | None = None
+    t_first: float | None = None
+    t_done: float | None = None
 
     def __post_init__(self):
         self.prompt = np.asarray(self.prompt, np.int32).reshape(-1)

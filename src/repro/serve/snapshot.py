@@ -47,6 +47,7 @@ import numpy as np
 
 from repro.checkpoint import CheckpointManager, decode_json, encode_json, load_checkpoint
 from repro.serve.queue import FAILED, Request, _rid
+from repro.serve.trace import phase
 
 
 def fresh_snapshot_stats() -> dict:
@@ -495,27 +496,26 @@ class SnapshotManager:
         paging path, persist the packed engine tree, rotate the journal
         generation, and re-persist any still-pending resubmit records so
         they survive the old generation being superseded."""
-        tracer = getattr(engine, "_tracer", None)
-        t0 = tracer.now_us() if tracer is not None else 0.0
-        while engine._inflight:
-            engine._reconcile(engine._inflight[0])
-        engine.pool.flush_dirty()
-        m = int(engine.megasteps)
-        tree = _capture(engine)
-        self.ckpt.save(m, tree,
-                       metadata={"megasteps": m,
-                                 "step_count": int(engine.step_count),
-                                 "journal": _journal_name(m)},
-                       block=True)
-        self._open_gen(m)
-        self._restored = False
-        for rec in self._resubmit:
-            if rec["ms"] > m:
-                self._append(rec)
-        self._last_cut = m
-        self.stats["snapshots_taken"] += 1
-        if tracer is not None:
-            tracer.span("snapshot_cut", t0, megastep=m)
+        with phase(getattr(engine, "_tracer", None),
+                   "snapshot_cut") as span:
+            while engine._inflight:
+                engine._reconcile(engine._inflight[0])
+            engine.pool.flush_dirty()
+            m = int(engine.megasteps)
+            tree = _capture(engine)
+            self.ckpt.save(m, tree,
+                           metadata={"megasteps": m,
+                                     "step_count": int(engine.step_count),
+                                     "journal": _journal_name(m)},
+                           block=True)
+            self._open_gen(m)
+            self._restored = False
+            for rec in self._resubmit:
+                if rec["ms"] > m:
+                    self._append(rec)
+            self._last_cut = m
+            self.stats["snapshots_taken"] += 1
+            span["megastep"] = m
         # journal retention follows snapshot retention: generations older
         # than the oldest kept snapshot can never be replayed again.
         kept = [int(fn.split("_")[1]) for fn in os.listdir(self.dir)
@@ -542,60 +542,57 @@ class SnapshotManager:
         ``engine.failed`` — instead of being replayed out of order.
         ``disarm`` drops scheduled crash events so the death just
         recovered from does not re-fire during replay."""
-        tracer = getattr(engine, "_tracer", None)
-        t0 = tracer.now_us() if tracer is not None else 0.0
-        tree, manifest = self.ckpt.restore(step)
-        m = int(manifest["step"])
-        _install(engine, _unpack(tree))
+        with phase(getattr(engine, "_tracer", None), "restore") as span:
+            tree, manifest = self.ckpt.restore(step)
+            m = int(manifest["step"])
+            _install(engine, _unpack(tree))
 
-        oracle, resub, casualties = [], {}, {}
-        broken = False
-        for gen in self._journal_gens():
-            if gen < m:
-                continue
-            with open(os.path.join(self.dir, _journal_name(gen))) as fh:
-                for line in fh:
-                    rec = _unframe(line)
-                    if rec is None:
-                        broken = True
-                        continue
-                    if rec["t"] == "b":
-                        if not broken:
-                            oracle.append(rec)
-                    elif rec["t"] == "s":
-                        # cut-time rewrites duplicate pending submits
-                        # across generations: first (replayable) copy wins.
-                        if rec["rid"] in resub or rec["rid"] in casualties:
+            oracle, resub, casualties = [], {}, {}
+            broken = False
+            for gen in self._journal_gens():
+                if gen < m:
+                    continue
+                with open(os.path.join(self.dir, _journal_name(gen))) as fh:
+                    for line in fh:
+                        rec = _unframe(line)
+                        if rec is None:
+                            broken = True
                             continue
-                        (resub if not broken else casualties)[rec["rid"]] = rec
+                        if rec["t"] == "b":
+                            if not broken:
+                                oracle.append(rec)
+                        elif rec["t"] == "s":
+                            # cut-time rewrites duplicate pending submits
+                            # across generations: first (replayable) copy wins.
+                            if rec["rid"] in resub or rec["rid"] in casualties:
+                                continue
+                            (resub if not broken else casualties)[rec["rid"]] = rec
 
-        for rid in sorted(casualties):
-            rec = casualties[rid]
-            r = Request(prompt=np.asarray(rec["prompt"], np.int32),
-                        max_new_tokens=int(rec["mnew"]),
-                        arrival_step=int(rec["arr"]),
-                        hint_path=rec["hint"], tenant=rec["ten"],
-                        rid=int(rec["rid"]))
-            r.state = FAILED
-            r.error = {"kind": "crash", "step": m,
-                       "detail": "journal truncated past this submit; "
-                                 "request lost at restore"}
-            r.done_step = int(engine.step_count)
-            engine.failed[r.rid] = r
-            self.stats["casualties"] += 1
+            for rid in sorted(casualties):
+                rec = casualties[rid]
+                r = Request(prompt=np.asarray(rec["prompt"], np.int32),
+                            max_new_tokens=int(rec["mnew"]),
+                            arrival_step=int(rec["arr"]),
+                            hint_path=rec["hint"], tenant=rec["ten"],
+                            rid=int(rec["rid"]))
+                r.state = FAILED
+                r.error = {"kind": "crash", "step": m,
+                           "detail": "journal truncated past this submit; "
+                                     "request lost at restore"}
+                r.done_step = int(engine.step_count)
+                engine.failed[r.rid] = r
+                self.stats["casualties"] += 1
 
-        self._oracle, self._oracle_pos = oracle, 0
-        self._resubmit = sorted(resub.values(), key=lambda r: (r["ms"], r["rid"]))
-        self._last_cut = None
-        self._restored = True
-        self.close()
-        if resub or casualties:
-            _rid.seek(1 + max([*resub, *casualties]))
-        if engine._fx is not None and disarm:
-            engine._fx.disarm_crashes()
-        if tracer is not None:
-            tracer.span("restore", t0, restored_step=m,
-                        casualties=len(casualties))
+            self._oracle, self._oracle_pos = oracle, 0
+            self._resubmit = sorted(resub.values(), key=lambda r: (r["ms"], r["rid"]))
+            self._last_cut = None
+            self._restored = True
+            self.close()
+            if resub or casualties:
+                _rid.seek(1 + max([*resub, *casualties]))
+            if engine._fx is not None and disarm:
+                engine._fx.disarm_crashes()
+            span.update(restored_step=m, casualties=len(casualties))
         return {"restored_step": m,
                 "journal_entries": len(oracle) + len(resub),
                 "pending_resubmits": len(self._resubmit),

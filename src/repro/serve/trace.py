@@ -22,21 +22,35 @@ Two clocks, deliberately:
     monotonic and non-overlapping by construction, and the idle minor
     direction of a duplex link shows up as literal white space.
 
+Every boundary phase goes through ``phase()``, which also opens a
+``jax.profiler.TraceAnnotation("serve.<phase>")`` whether or not a
+tracer is attached: any profile of a serving process shows the engine's
+phases on the device's clock, at the cost of one inactive annotation
+per phase when no profiler session is running.
+
 ``export()`` writes Chrome/Perfetto ``trace.json`` (open at
 https://ui.perfetto.dev): pid 1 = the engine's host-clock spans, pid 2
 = the modelled memory hierarchy, one thread per phase / per channel
-direction, fault instants riding the channel tracks.
+direction, fault instants riding the channel tracks; request spans are
+async slices on a ``requests`` track, keyed by rid.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import time
 
-from repro.core.metrics import MetricsRegistry
+import jax
 
-#: span names the engine emits — the span taxonomy (README).
-PHASES = ("plan", "dispatch", "reconcile", "snapshot_cut", "restore")
+#: boundary span names the engine emits through ``phase()`` — the span
+#: taxonomy (README); each is also the profiler annotation
+#: ``serve.<name>``.
+PHASES = ("plan", "admit", "dispatch", "reconcile", "readback",
+          "snapshot_cut", "restore")
+_ANNOTATIONS = {p: f"serve.{p}" for p in PHASES}
+#: per-request span names: submit -> admit -> first token -> done.
+REQUEST_SPANS = ("queued", "prefill", "decode")
 
 _HOST_PID = 1       # host-clock process (boundary spans)
 _MODEL_PID = 2      # modelled-clock process (channels + faults)
@@ -56,6 +70,8 @@ class Tracer:
         self._epoch = time.perf_counter_ns()
         # host-clock spans: (name, t0_us, dur_us, args)
         self.spans: list[tuple[str, float, float, dict]] = []
+        # per-request spans, same shape; args carry the request's rid
+        self.request_spans: list[tuple[str, float, float, dict]] = []
         # modelled-clock busy intervals per track:
         # track -> [(t0_us, dur_us, name, args), ...]
         self.timelines: dict[str, list] = {}
@@ -66,18 +82,31 @@ class Tracer:
         self.model_us = 0.0
         # per-track modelled busy totals (combined, read, write)
         self._busy: dict[str, dict] = {}
-        self.metrics = MetricsRegistry()
 
     # -- clocks --------------------------------------------------------------
     def now_us(self) -> float:
         return (time.perf_counter_ns() - self._epoch) / 1e3
+
+    def at_us(self, t_s: float) -> float:
+        """A ``time.perf_counter()`` reading on this tracer's clock."""
+        return t_s * 1e6 - self._epoch / 1e3
 
     # -- host-clock spans ----------------------------------------------------
     def span(self, name: str, t0_us: float, **args) -> None:
         """Close a boundary span opened at ``t0_us`` (host clock)."""
         dur = max(0.0, self.now_us() - t0_us)
         self.spans.append((name, t0_us, dur, args))
-        self.metrics.observe(f"span.{name}.us", dur)
+
+    def request(self, req) -> None:
+        """A finished request's ``queued``/``prefill``/``decode`` spans,
+        from its ``t_submit``/``t_admit``/``t_first``/``t_done`` stamps
+        (a span whose ends were not both stamped is left out)."""
+        marks = (req.t_submit, req.t_admit, req.t_first, req.t_done)
+        for name, a, b in zip(REQUEST_SPANS, marks, marks[1:]):
+            if a is not None and b is not None:
+                self.request_spans.append(
+                    (name, self.at_us(a), max(0.0, (b - a) * 1e6),
+                     {"rid": req.rid}))
 
     def counter(self, name: str, value: float) -> None:
         """One sample of a host-clock counter series (Perfetto "C")."""
@@ -92,7 +121,6 @@ class Tracer:
         channel tracks); ``clock="host"`` to the span axis."""
         ts = self.model_us if clock == "model" else self.now_us()
         self.instants.append((clock, track, name, ts, args or {}))
-        self.metrics.inc(f"instant.{track}.{name}")
 
     # -- modelled-clock channel timelines ------------------------------------
     def channel_transaction(self, entries, advance_us: float,
@@ -173,7 +201,8 @@ class Tracer:
         return {"phase_us": self.phase_totals(),
                 "duplex_util": self.duplex_util(),
                 "model_us": round(self.model_us, 3),
-                "events": (len(self.spans) + len(self.instants)
+                "events": (len(self.spans) + len(self.request_spans)
+                           + len(self.instants)
                            + sum(len(v) for v in self.timelines.values())),
                 "instants": len(self.instants)}
 
@@ -206,6 +235,15 @@ class Tracer:
                        "tid": tid(_HOST_PID, name), "ts": round(t0, 3),
                        "dur": round(dur, 3), "cat": "boundary",
                        "args": args})
+        for name, t0, dur, args in self.request_spans:
+            # async slices: a request's spans share its rid as their id,
+            # and requests overlap one another on the one track
+            common = {"name": name, "pid": _HOST_PID,
+                      "tid": tid(_HOST_PID, "requests"), "cat": "request",
+                      "id": args["rid"]}
+            ev.append({**common, "ph": "b", "ts": round(t0, 3),
+                       "args": args})
+            ev.append({**common, "ph": "e", "ts": round(t0 + dur, 3)})
         for track, ivals in sorted(self.timelines.items()):
             t = tid(_MODEL_PID, track)
             for t0, dur, name, args in ivals:
@@ -237,3 +275,18 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_perfetto(), f)
         return path
+
+
+@contextlib.contextmanager
+def phase(tracer: Tracer | None, name: str, **args):
+    """One boundary phase: always a ``jax.profiler.TraceAnnotation``
+    named ``serve.<name>`` (close to free with no profiler session
+    active), and, with a tracer attached, a host-clock span carrying
+    ``args``. Yields the args dict, so the body can add what it learns
+    (admissions, page-ins) before the span closes. A phase that raises
+    records no span."""
+    with jax.profiler.TraceAnnotation(_ANNOTATIONS[name]):
+        t0 = tracer.now_us() if tracer is not None else 0.0
+        yield args
+        if tracer is not None:
+            tracer.span(name, t0, **args)

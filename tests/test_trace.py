@@ -18,13 +18,22 @@ Contracts under test:
     instant markers on the ``faults`` track;
   * sharded — a (2, 2) mesh trace namespaces each data rank's channel
     tracks ``shard<s>/`` and bills the model-axis collectives on an
-    ``ici:model`` track.
+    ``ici:model`` track;
+  * requests — every finished request carries ordered host-clock stamps
+    (submit <= admit <= first token <= done) at any pipeline depth, and
+    a tracer turns them into one ``queued``/``prefill``/``decode`` span
+    each, keyed by rid;
+  * profiler — the boundary phases appear as ``serve.*`` annotations in
+    a ``jax.profiler`` trace, and the megastep's ops carry their
+    ``megastep/*`` scopes in the compiled program's metadata.
 
 Multi-device cases skip below 4 devices — CI runs the sharded lane
 under ``XLA_FLAGS=--xla_force_host_platform_device_count=4``.
 """
 
+import glob
 import json
+import os
 
 import jax
 import numpy as np
@@ -32,7 +41,8 @@ import pytest
 
 from repro.models import registry as R
 from repro.serve import EngineConfig, ServeEngine, Tracer
-from repro.serve.trace import PHASES
+from repro.serve.engine import _megastep_math
+from repro.serve.trace import PHASES, REQUEST_SPANS
 
 DEVICES = jax.device_count()
 
@@ -252,3 +262,59 @@ class TestShardedTrace:
                         if e["ph"] == "M" and e["name"] == "thread_name"}
         assert any(n.startswith("shard0/") for n in thread_names)
         assert any(n.startswith("ici:model") for n in thread_names)
+
+
+class TestRequestSpans:
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_stamps_ordered(self, api, params, depth):
+        eng = ServeEngine(api, params, _cfg(pipeline_depth=depth))
+        _drive(eng)
+        done = list(eng.completed.values())
+        assert len(done) == 5
+        for r in done:
+            assert r.t_submit <= r.t_admit <= r.t_first <= r.t_done, r.rid
+
+    def test_one_span_of_each_kind_per_request(self, api, params, tmp_path):
+        tr = Tracer()
+        eng = ServeEngine(api, params, _cfg(trace=tr))
+        _drive(eng)
+        by_rid = {}
+        for name, t0, dur, args in tr.request_spans:
+            by_rid.setdefault(args["rid"], []).append((name, t0, dur))
+        assert set(by_rid) == set(eng.completed)
+        for rid, spans in by_rid.items():
+            assert [n for n, _, _ in spans] == list(REQUEST_SPANS)
+            r = eng.completed[rid]
+            assert spans[0][1] == pytest.approx(tr.at_us(r.t_submit))
+            # the three spans tile submit..done with no gap
+            for (_, a, da), (_, b, _) in zip(spans, spans[1:]):
+                assert a + da == pytest.approx(b)
+        doc = json.load(open(eng.export_trace(str(tmp_path / "t.json"))))
+        begins = [e for e in doc["traceEvents"] if e["ph"] == "b"]
+        assert len(begins) == 3 * len(by_rid)
+        assert {e["id"] for e in begins} == set(by_rid)
+
+
+class TestProfilerAnnotations:
+    def test_serve_phases_in_profiler_trace(self, api, params, tmp_path):
+        from jax.profiler import ProfileData
+        eng = ServeEngine(api, params, _cfg(pipeline_depth=2))
+        with jax.profiler.trace(str(tmp_path)):
+            _drive(eng, n=3, gen=6)
+        path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                          recursive=True)
+        names = {e.name for plane in ProfileData.from_file(path).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events}
+        assert {"serve.plan", "serve.admit", "serve.dispatch",
+                "serve.reconcile", "serve.readback"} <= names
+
+    def test_megastep_scopes_in_op_metadata(self, api, params):
+        eng = ServeEngine(api, params, _cfg())
+        assert eng.paged
+        mega = jax.jit(_megastep_math(api, eng.cfg.prefill_chunk, 2,
+                                      eng.cfg.block_tokens))
+        text = mega.lower(params, eng.cache, eng._dev).compile().as_text()
+        for scope in ("megastep/model", "megastep/writethrough",
+                      "megastep/pack"):
+            assert f"/{scope}/" in text, scope
