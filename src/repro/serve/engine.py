@@ -290,17 +290,25 @@ def _extract_blocks_math(k, v, slot_idx, t0, *, block_tokens: int):
     — the block-table-indexed read the pool pages. Plain traceable math:
     the megastep program inlines it inside its scan (staging the filled
     blocks right after the inner step that filled them); the jitted
-    ``_extract_blocks_impl`` wraps it for stand-alone use."""
-    W = k.shape[2]
-    idx = ((t0[:, None] + jnp.arange(block_tokens)[None, :]) % W
-           ).astype(jnp.int32)
+    ``_extract_blocks_impl`` wraps it for stand-alone use.
 
-    def take(arr):
-        a = jnp.moveaxis(arr, 1, 0)[slot_idx]               # (n, L, W, KV, hd)
-        ix = idx[:, None, :, None, None]
-        ix = jnp.broadcast_to(
-            ix, a.shape[:2] + (block_tokens,) + a.shape[3:])
-        return jnp.take_along_axis(a, ix, axis=2)           # (n, L, bt, KV, hd)
+    Token j of row i is ring position ``(t0[i] + j) % W``, read as
+    contiguous slices of the cache, so the rows lower to one slice gather
+    per tensor and never to a gather of single elements. When W is a
+    multiple of ``block_tokens``, a block-aligned ``t0`` (as every caller
+    passes) never straddles the ring's end: each row is one slice of
+    ``block_tokens`` tokens. Otherwise each token is a slice of its own."""
+    L, _, W, KV, hd = k.shape
+    piece = block_tokens if W % block_tokens == 0 else 1
+    offs = jnp.arange(0, block_tokens, piece, dtype=jnp.int32)
+
+    def row(arr, s, t):                                     # (L, bt, KV, hd)
+        parts = jax.vmap(lambda p: lax.dynamic_slice(
+            arr, (0, s, p, 0, 0), (L, 1, piece, KV, hd)))((t + offs) % W)
+        return jnp.moveaxis(parts, 0, 1).reshape(L, block_tokens, KV, hd)
+
+    def take(arr):                                          # (n, L, bt, KV, hd)
+        return jax.vmap(row, in_axes=(None, 0, 0))(arr, slot_idx, t0)
 
     kv = jnp.stack([take(k), take(v)], axis=2)
     kv = jnp.moveaxis(kv, 3, 1)                             # (n, bt, L, 2, KV, hd)
@@ -316,8 +324,8 @@ def _extract_blocks_impl(k, v, slot_idx, t0, *, block_tokens: int):
 
 def _extract_blocks(cache, slot_idx, t0, block_tokens: int) -> jnp.ndarray:
     """Compat wrapper over ``_extract_blocks_impl`` accepting the cache
-    dict and python index lists (tests use it; the engine calls the jitted
-    impl with fixed-width device vectors directly)."""
+    dict and python index lists (tests use it; the engine inlines the
+    math in its megastep). Each ``t0`` must be block-aligned."""
     return _extract_blocks_impl(
         cache["k"], cache["v"],
         jnp.asarray(np.asarray(slot_idx, np.int32)),

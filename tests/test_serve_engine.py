@@ -2,6 +2,9 @@
 a static batch, paging batches into one kernel call per step, and the
 policy-driven queue orders admission."""
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,6 +26,15 @@ def api():
 @pytest.fixture(scope="module")
 def params(api):
     return api.init(jax.random.PRNGKey(0))
+
+
+def _dense_block(cache, slot, t0, bt):
+    """Tokens t0 .. t0+bt-1 of one slot, read from the dense cache by
+    NumPy indexing, in the pool's (bt, L * 2 * KV * hd) layout."""
+    k, v = (np.asarray(cache[n], np.float32) for n in ("k", "v"))
+    pos = (t0 + np.arange(bt)) % k.shape[2]
+    kv = np.stack([k[:, slot, pos], v[:, slot, pos]], axis=1)
+    return kv.transpose(2, 0, 1, 3, 4).reshape(bt, -1)
 
 
 def _cfg(**kw):
@@ -192,7 +204,6 @@ class TestBatchedPaging:
         """Pool blocks hold the *real* KV: every resident block of an
         active request matches the dense cache within int8 round-trip
         tolerance (catches stale/dummy entries in freshly filled blocks)."""
-        from repro.serve.engine import _extract_blocks
         eng = ServeEngine(api, params, _cfg(max_batch=2, hbm_blocks=8))
         prompts = jax.random.randint(jax.random.PRNGKey(6), (2, 6), 0,
                                      api.cfg.vocab)
@@ -207,13 +218,56 @@ class TestBatchedPaging:
             for bi, blk in enumerate(r.blocks):
                 if slot_of[blk] < 0:
                     continue
-                dense = np.asarray(_extract_blocks(
-                    eng.cache, [r.slot], [bi * bt], bt)[0], np.float32)
+                dense = _dense_block(eng.cache, r.slot, bi * bt, bt)
                 pooled = np.asarray(eng.pool.hbm[slot_of[blk]], np.float32)
                 amax = np.abs(dense).max()
                 assert np.abs(pooled - dense).max() <= amax / 127.0 + 0.05
                 checked += 1
         assert checked > 0
+
+    @pytest.mark.parametrize("W, bt, slot_idx, t0", [
+        pytest.param(64, 4, [0, 2, 1, 3, 2], [0, 8, 60, 4, 32],
+                     id="several_slots"),
+        pytest.param(64, 4, [1, 1, 1, 3, 3, 3], [8, 12, 16, 0, 4, 8],
+                     id="max_fills_3"),
+        pytest.param(64, 4, [0, 3, 2, 1], [64, 124, 200, 60],
+                     id="ring_wrap"),
+        pytest.param(30, 4, [0, 1, 2, 3, 1], [0, 28, 56, 88, 116],
+                     id="width_not_multiple"),
+    ])
+    def test_extract_blocks_matches_numpy_slices(self, W, bt, slot_idx, t0):
+        """Staged blocks equal, bit for bit, the same tokens read from the
+        dense cache by NumPy indexing: across slots, for consecutive
+        blocks of one slot, past the ring's end, and where the ring's
+        width is no multiple of the block (blocks straddle its end)."""
+        from repro.serve.engine import _extract_blocks
+        rng = np.random.default_rng(W)
+        L, B, KV, hd = 3, 4, 2, 8
+        cache = {n: jnp.asarray(rng.standard_normal((L, B, W, KV, hd)),
+                                jnp.bfloat16) for n in ("k", "v")}
+        got = _extract_blocks(cache, slot_idx, t0, bt)
+        assert got.dtype == jnp.bfloat16
+        want = np.stack([_dense_block(cache, s, t, bt)
+                         for s, t in zip(slot_idx, t0)])
+        np.testing.assert_array_equal(np.asarray(got, np.float32), want)
+
+    def test_writethrough_gathers_whole_blocks(self, api, params):
+        """The paged K=8 megastep stages each block as slices of at least
+        ``block_tokens`` tokens: no gather of single elements, and none
+        that reads a slot's whole ring."""
+        from repro.serve.engine import _megastep_math
+        eng = ServeEngine(api, params, _cfg())
+        L, _, W, KV, hd = eng.cache["k"].shape
+        bt = eng.cfg.block_tokens
+        mega = jax.jit(_megastep_math(api, eng.cfg.prefill_chunk, 8, bt))
+        text = mega.lower(params, eng.cache, eng._dev).compile().as_text()
+        tokens = [
+            math.prod(map(int, m.group(1).split(","))) // (L * KV * hd)
+            for line in text.splitlines()
+            if " gather(" in line and "/megastep/writethrough/" in line
+            for m in [re.search(r"slice_sizes=\{([0-9,]+)\}", line)]]
+        assert tokens
+        assert all(bt <= n < W for n in tokens), tokens
 
     def test_paging_disabled_still_serves(self, api, params):
         eng = ServeEngine(api, params, _cfg(paging=False))
