@@ -4,7 +4,6 @@ value bytes of each processed token's real context, over its device time
 times the chip's HBM bandwidth, in percent. A decode step is bound by
 bytes, so this is the larger of its two roofline terms."""
 
-from bench import counts
 from bench.metrics import megastep_device_ms_per_step as mega
 from bench.trace_reduce import program_s
 
@@ -15,8 +14,8 @@ def read(run):
     t = program_s(run.trace, mega.match)
     if t <= 0:
         return None
-    wb = counts.weight_bytes(run.sizes)
+    wb = run.family.weight_bytes(run.sizes)
     total = sum(wb * b.micro_steps
-                + counts.context_kv_bytes(run.sizes, b.positions)
+                + run.family.context_kv_bytes(run.sizes, b.positions)
                 for b in run.slice_boundaries)
     return 100.0 * total / (t * run.peaks["hbm_bytes_s"])

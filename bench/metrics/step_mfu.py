@@ -2,13 +2,11 @@
 and generated, attention over each token's real context), over the
 window's seconds times the chip's bf16 peak, in percent."""
 
-from bench import counts
-
 
 def read(run):
     if not run.window.boundaries:
         return None
-    flops = sum(counts.positions_flops(run.sizes, b.positions)
+    flops = sum(run.family.positions_flops(run.sizes, b.positions)
                 for b in run.window.boundaries)
     if flops <= 0:
         return None

@@ -56,7 +56,8 @@ class RunData:
     tokens_in_window: int
     stopped: float            # host time the run stopped serving
     pool_stats: dict          # the pool's own counters (paging_stats)
-    sizes: object             # weights.Sizes
+    sizes: object             # the family's sizes
+    family: object            # the family module (bench/families)
     peaks: dict
     chips: int
     trace: object = None      # trace_reduce.Trace of the traced slice
@@ -95,30 +96,37 @@ def devices_for(chips: int, require_accelerator: bool):
     return devs[:chips]
 
 
+def family_module(config: dict):
+    """The model family module (``bench/families/<name>.py``) that the
+    configuration names in ``program.family``."""
+    from bench.spec import SpecError
+    name = config.get("program", {}).get("family")
+    if name is None:
+        raise SpecError("the configuration file names no model family "
+                        "(program.family)")
+    module = f"bench.families.{name}"
+    try:
+        return importlib.import_module(module)
+    except ModuleNotFoundError as e:
+        if e.name != module:
+            raise
+        raise SpecError(f"no model family {name!r} (program.family): "
+                        f"bench/families has no {name}.py") from None
+
+
+def _model(config: dict):
+    """The configuration's family, the program's model API that the
+    family builds and checks against the program's own entry, and the
+    family's sizes."""
+    family = family_module(config)
+    sz = family.sizes(config)
+    return family, family.build(config, sz), sz
+
+
 def build_model(config: dict):
-    """The program's model API for the configuration, checked against the
-    program's own entry for the architecture."""
-    from repro.configs import get_config
-    from repro.models import registry
-    from repro.models.transformer import LMConfig
-    from bench.weights import Sizes
-    sz = Sizes.of(config)
-    if sz.rope_pct != 1.0:
-        raise ValueError("the served program rotates whole heads; this "
-                         "configuration states partial rotary")
-    lm = LMConfig(name=config["program"]["arch"], num_layers=sz.layers,
-                  d_model=sz.d_model, num_heads=sz.heads,
-                  num_kv_heads=sz.kv_heads, head_dim=sz.head_dim,
-                  d_ff=sz.d_ff, vocab=sz.vocab, rope_theta=sz.rope_theta,
-                  tie_embeddings=sz.tied)
-    prog = get_config(config["program"]["arch"])
-    for f in ("num_layers", "d_model", "num_heads", "num_kv_heads",
-              "d_ff", "vocab", "tie_embeddings", "rope_theta"):
-        if getattr(prog, f) != getattr(lm, f):
-            raise ValueError(f"the program's {prog.name} has {f}="
-                             f"{getattr(prog, f)}, the configuration "
-                             f"file {getattr(lm, f)}")
-    return registry._lm_api(config["program"]["arch"], lm), sz
+    """The program's model API for the configuration, and its sizes."""
+    _, api, sz = _model(config)
+    return api, sz
 
 
 def check_tree(api, params) -> None:
@@ -151,6 +159,7 @@ class Served:
     engine: object
     params: object
     sizes: object
+    family: object
     ecfg: object
     devs: list
     peaks: dict
@@ -169,20 +178,22 @@ def prepare(cell, seed: int, *, require_accelerator: bool = True,
     from bench import peaks as peaks_mod, warmup
     from bench.serve_loop import BenchEngine
     from bench.traffic import max_tokens
-    from bench.weights import make_params
 
     if peaks is None:
         peaks = peaks_mod.peaks_for(devs[0].device_kind)
-    api, sz = model if model is not None else build_model(cell.config)
+    if model is None:
+        family, api, sz = _model(cell.config)
+    else:
+        family, (api, sz) = family_module(cell.config), model
     ecfg = EngineConfig(**cell.config["engine"])
     if max_tokens(cell.traffic) > ecfg.cache_len:
         raise ValueError("the traffic's longest request exceeds cache_len")
-    params = make_params(sz, seed)
+    params = family.make_params(sz, seed)
     check_tree(api, params)
     engine = (engine_factory or BenchEngine)(api, params, ecfg)
     warmup.warm(engine, sz.vocab)
     return Served(cell=cell, engine=engine, params=params, sizes=sz,
-                  ecfg=ecfg, devs=devs, peaks=peaks, api=api)
+                  family=family, ecfg=ecfg, devs=devs, peaks=peaks, api=api)
 
 
 class CompileLog:
@@ -272,7 +283,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
                   tokens_in_window=tokens_in_window(window),
                   stopped=stopped,
                   pool_stats=dict(engine.pool.stats) if engine.paged else {},
-                  sizes=served.sizes, peaks=served.peaks, chips=cell.chips)
+                  sizes=served.sizes, family=served.family,
+                  peaks=served.peaks, chips=cell.chips)
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs), "memory_peak_bytes": mem}
     extra = {}

@@ -8,7 +8,7 @@ import pytest
 
 from bench.references import dense_lm
 from bench.run import build_model
-from bench.weights import make_params
+from bench.families.dense_lm import make_params
 from bench_fixtures import register_tiny, tiny_config
 from repro.models import transformer
 

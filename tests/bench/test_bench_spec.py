@@ -89,3 +89,23 @@ def test_every_cell_loads_and_reports_enough(cell):
     assert c.per_layer
     assert c.config["engine"]["pipeline_depth"] == 2
     assert set(c.config["check"]["limits"]) <= {"logit_gap", "kv_err"}
+
+
+# the metrics each cell reported before the five that every cell reports
+# lost their ``workloads`` lists; a cell added later reports those five
+# with no edit to their entries
+PER_LAYER = {
+    "smollm135m-chat": [
+        "host_ms_per_step", "megastep_device_ms_per_step", "step_mfu",
+        "paging_device_ms_per_step", "megastep_hbm_roofline",
+        "device_idle_share", "boundary_wait_p95_ms", "queue_wait_p95_ms",
+        "prefill_p95_ms"],
+    "stablelm3b-decode": [
+        "host_ms_per_step", "megastep_device_ms_per_step", "step_mfu",
+        "megastep_hbm_roofline", "device_idle_share"],
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PER_LAYER))
+def test_each_cell_reports_the_same_per_layer_metrics(cell):
+    assert [m["name"] for m in load_cell(cell).per_layer] == PER_LAYER[cell]
