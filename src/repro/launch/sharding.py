@@ -4,9 +4,10 @@ Strategy (DESIGN §5): 2D FSDP × TP for dense params — d_model-ish dims shard
 over the ``data`` axis (FSDP), head/ffn/vocab dims over ``model`` (TP);
 MoE expert dims shard over ``model`` when there are enough experts
 (kimi-k2: 384/16) and over the ffn dim otherwise (mixtral: 8 experts,
-Megatron-style expert-TP). The ``pod`` axis is pure DP by default; archs
-whose params exceed one pod's HBM (kimi-k2, mixtral) extend FSDP over
-``pod`` too.
+Megatron-style expert-TP); latent attention (kimi-k2) shards its
+down-projections over FSDP and its per-head up-projections over
+``model``. The ``pod`` axis is pure DP by default; archs whose params
+exceed one pod's HBM (kimi-k2, mixtral) extend FSDP over ``pod`` too.
 
 Rules are (regex over the param path) -> PartitionSpec template, resolved
 against the mesh at hand. Anything unmatched replicates (correct, logged
@@ -22,6 +23,7 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.launch.mesh import axis_size, data_axes
+from repro.models.mla_moe import MLAMoEConfig
 
 # archs whose parameters must shard across pods as well (capacity)
 FSDP_OVER_POD = frozenset({"kimi-k2-1t-a32b", "mixtral-8x7b"})
@@ -45,7 +47,7 @@ def parallelism(api, mesh):
          else ("data",))
     return F, "model", data_axes(mesh)
 
-_STACKED = re.compile(r"^(layers|enc_layers|dec_layers)/")
+_STACKED = re.compile(r"^(layers|dense_layers|enc_layers|dec_layers)/")
 
 
 def _param_rules(F, T, moe_expert_sharded: bool):
@@ -62,11 +64,16 @@ def _param_rules(F, T, moe_expert_sharded: bool):
         (r"attn/w[qkv]$", P(F, T)),
         (r"attn/wo$", P(T, F)),
         (r"attn/b[qkv]$", P(T)),
-        (r"(mlp|cm)/(w_gate|w_up|w_in|wk)$", P(F, T)),
-        (r"(mlp|cm)/(w_down|w_out|wv)$", P(T, F)),
+        # latent attention: down-projections to the latents, then per-head
+        # up-projections
+        (r"attn/(wq_a|wkv_a)$", P(F, None)),
+        (r"attn/(wq_b|wkv_b)$", P(F, T)),
+        (r"(mlp|cm|shared)/(w_gate|w_up|w_in|wk)$", P(F, T)),
+        (r"(mlp|cm|shared)/(w_down|w_out|wv)$", P(T, F)),
         (r"mlp/b_in$", P(T)),
         (r"cm/wr$", P(F, T)),
         (r"moe/router$", P(F, None)),
+        (r"moe/bias$", P(None)),
         (r"moe/(w_gate|w_up)$", moe_up),
         (r"moe/w_down$", moe_down),
         # rwkv6 time-mix
@@ -93,6 +100,7 @@ def _cache_rules(DP, T):
         # (regex, preferred spec, alt dim for T if preferred T dim fails)
         (r"(^|/)(k|v)$", P(None, DP, None, T, None), 2),    # (L,B,W,KV,hd)
         (r"(^|/)pos$", P(None, DP, None), None),            # (L,B,W)
+        (r"(^|/)(c_kv|k_pe)$", P(None, DP, None, None), None),  # (L,B,W,r)
         (r"cross_(k|v)$", P(None, DP, None, T, None), 2),   # (L,B,Senc,KV,hd)
         (r"^wkv$", P(None, DP, T, None, None), None),       # (L,B,H,hs,hs)
         (r"^(tm|cm)_last$", P(None, DP, None), None),       # (L,B,D)
@@ -124,12 +132,19 @@ def _fit(spec: P, rank: int, stacked: bool) -> P:
     return P(*parts)
 
 
+def _experts(cfg) -> int:
+    """Experts stacked in each MoE weight: those an MLA/MoE config holds,
+    or the transformer's ``moe`` spec; 0 without experts."""
+    if isinstance(cfg, MLAMoEConfig):
+        return cfg.held[1]
+    moe = getattr(cfg, "moe", None)
+    return moe.num_experts if moe is not None else 0
+
+
 def param_specs(api, params_shape, mesh) -> tuple[dict, list[str]]:
     """PartitionSpec tree for a model's params. Returns (tree, unmatched)."""
     F, T, _dp = parallelism(api, mesh)
-    moe = getattr(api.cfg, "moe", None)
-    expert_sharded = bool(T and moe
-                          and moe.num_experts >= mesh.shape[T])
+    expert_sharded = bool(T and _experts(api.cfg) >= mesh.shape[T])
     rules = _param_rules(F, T, expert_sharded)
 
     paths, leaves, treedef = _leaf_paths(params_shape)

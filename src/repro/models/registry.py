@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs as configs_lib
-from repro.models import encdec, hybrid, rwkv6, transformer
+from repro.models import encdec, hybrid, mla_moe, rwkv6, transformer
 from repro.models.transformer import LMConfig
 
 
@@ -181,10 +181,28 @@ def _encdec_api(arch_id: str, cfg) -> ModelAPI:
     )
 
 
+def _mla_moe_api(arch_id: str, cfg: mla_moe.MLAMoEConfig) -> ModelAPI:
+    return ModelAPI(
+        arch_id=arch_id, family=FAMILY.get(arch_id, "moe"), cfg=cfg,
+        init=functools.partial(mla_moe.init, cfg=cfg),
+        loss_fn=lambda params, batch: mla_moe.loss_fn(params, cfg, batch),
+        forward=lambda params, batch: mla_moe.forward(params, cfg,
+                                                      batch["tokens"]),
+        init_cache=lambda batch, cache_len: mla_moe.init_cache(
+            cfg, batch, cache_len),
+        decode_step=lambda params, cache, tokens, pos: mla_moe.decode_step(
+            params, cfg, cache, tokens, pos),
+        param_count=cfg.param_count(),
+        active_param_count=cfg.active_param_count(),
+    )
+
+
 def build(arch_id: str, smoke: bool = False) -> ModelAPI:
     cfg = configs_lib.get_config(arch_id, smoke=smoke)
     if isinstance(cfg, LMConfig):
         return _lm_api(arch_id, cfg)
+    if isinstance(cfg, mla_moe.MLAMoEConfig):
+        return _mla_moe_api(arch_id, cfg)
     if isinstance(cfg, rwkv6.RWKVConfig):
         return _rwkv_api(arch_id, cfg)
     if isinstance(cfg, hybrid.HybridConfig):

@@ -1,12 +1,13 @@
 """Decoder-only transformer LM (dense + MoE + SWA + prefix-LM).
 
-Covers seven of the ten assigned architectures: smollm-135m, stablelm-3b,
-qwen2.5-14b, llama3.2-3b, mixtral-8x7b, kimi-k2-1t-a32b, paligemma-3b (the
-VLM: a gemma decoder with prefix-LM masking over stubbed patch embeddings).
+Covers six of the ten assigned architectures: smollm-135m, stablelm-3b,
+qwen2.5-14b, llama3.2-3b, mixtral-8x7b, paligemma-3b (the VLM: a gemma
+decoder with prefix-LM masking over stubbed patch embeddings). Kimi-K2's
+latent attention and sparse experts are ``models/mla_moe.py``.
 
-Layers are stacked with a leading L axis and consumed by ``lax.scan`` so the
-61-layer kimi config lowers to a compact HLO (critical for multi-pod
-dry-run compile times).
+Layers are stacked with a leading L axis and consumed by ``lax.scan`` so a
+deep config lowers to a compact HLO (critical for multi-pod dry-run
+compile times).
 """
 
 from __future__ import annotations

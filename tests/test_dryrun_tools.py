@@ -91,6 +91,10 @@ class TestParallelismPolicy:
 
     def test_fsdp_over_pod_for_kimi(self):
         mesh = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
-        F, T, DP = parallelism(R.build("kimi-k2-1t-a32b"), mesh)
+        api = R.build("kimi-k2-1t-a32b")
+        F, T, DP = parallelism(api, mesh)
         assert F == ("pod", "data")
         assert DP == ("pod", "data")
+        # heads and the 384 routed experts split over the model axis
+        assert T == "model"
+        assert (api.cfg.num_heads, api.cfg.held[1]) == (64, 384)

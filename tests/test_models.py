@@ -79,12 +79,25 @@ class TestSmoke:
             "qwen2.5-14b": (48, 5120, 40, 8, 13824, 152064),
             "llama3.2-3b": (28, 3072, 24, 8, 8192, 128256),
             "mixtral-8x7b": (32, 4096, 32, 8, 14336, 32000),
-            "kimi-k2-1t-a32b": (61, 7168, 64, 8, 2048, 163840),
             "whisper-base": (6, 512, 8, 8, 2048, 51865),
             "zamba2-7b": (81, 3584, 32, 32, 14336, 32000),
             "paligemma-3b": (18, 2048, 8, 1, 16384, 257216),
         }
         cfg = R.build(arch).cfg
+        if arch == "kimi-k2-1t-a32b":
+            # Kimi-K2-Instruct's config.json: MLA, 384 routed experts of
+            # 2048 top 8 plus one shared, one leading dense layer of 18432
+            assert (cfg.num_layers, cfg.d_model, cfg.num_heads,
+                    cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                    cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.d_ff,
+                    cfg.moe_d_ff, cfg.n_routed_experts, cfg.n_shared_experts,
+                    cfg.top_k, cfg.first_k_dense, cfg.vocab) == \
+                (61, 7168, 64, 1536, 512, 128, 64, 128, 18432, 2048, 384, 1,
+                 8, 1, 163840)
+            assert (cfg.routed_scaling_factor, cfg.rope_theta,
+                    cfg.rope_factor, cfg.original_max_position) == \
+                (2.827, 50000.0, 32.0, 4096)
+            return
         if arch == "rwkv6-7b":
             assert (cfg.num_layers, cfg.d_model, cfg.d_ff, cfg.vocab) == \
                 (32, 4096, 14336, 65536)
@@ -202,5 +215,6 @@ class TestParamCounts:
         assert api.param_count / 1e9 == pytest.approx(expected_b, rel=0.1)
 
     def test_kimi_active_params(self):
+        # published as 1.04T total, 32B activated
         api = R.build("kimi-k2-1t-a32b")
-        assert api.active_param_count / 1e9 == pytest.approx(31.0, rel=0.1)
+        assert api.active_param_count / 1e9 == pytest.approx(32.0, rel=0.1)

@@ -81,6 +81,7 @@ class TestCacheSpecs:
         ("zamba2-7b", "long_500k"),
         ("whisper-base", "decode_32k"),
         ("mixtral-8x7b", "long_500k"),
+        ("kimi-k2-1t-a32b", "decode_32k"),  # latent cache (L,B,W,r)
     ])
     def test_decode_cells_divisible(self, arch, shape):
         api = R.build(arch)
@@ -145,6 +146,15 @@ class TestFsdpOverPod:
         gate = specs["layers"]["moe"]["w_gate"]   # (L, E, D, FF)
         assert gate[1] == "model"                  # experts over TP
         assert gate[2] == ("pod", "data")          # FSDP spans pods
+        # latent attention: (L, D, q_rank) down, (L, q_rank, H*192) up
+        attn = specs["layers"]["attn"]
+        assert attn["wq_a"] == P(None, ("pod", "data"), None)
+        assert attn["wq_b"] == P(None, ("pod", "data"), "model")
+        assert attn["wkv_b"] == P(None, ("pod", "data"), "model")
+        shared = specs["layers"]["shared"]["w_gate"]   # (L, D, 2048)
+        assert shared == P(None, ("pod", "data"), "model")
+        dense = specs["dense_layers"]["mlp"]["w_down"]  # (1, 18432, D)
+        assert dense == P(None, "model", ("pod", "data"))
 
     def test_dense_params_replicate_over_pod(self):
         api = R.build("llama3.2-3b")
