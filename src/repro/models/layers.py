@@ -237,15 +237,20 @@ def attn_apply(params, x, spec: AttnSpec, positions=None,
     return out.reshape(B, S, -1) @ params["wo"]
 
 
-def attn_decode_step(params, x, cache, pos, spec: AttnSpec):
-    """One-token decode. x: (B, 1, D); cache: {"k","v": (B, W, KV, hd)}.
+def attn_decode_layer(params, x, cache, layer, pos, spec: AttnSpec):
+    """One-token decode of layer ``layer`` over a stacked ring cache.
 
-    ``pos`` is the absolute position (B,) of the new token. The cache is a
-    ring buffer of width W (=window for SWA, =max_len for full attention);
-    entries older than the window are masked via stored positions.
+    x: (B, 1, D); cache: {"k","v": (L, B, W, KV, hd), "pos": (L, B, W)},
+    carried through the caller's layer scan; ``layer`` may be traced.
+    ``pos`` is the absolute position (B,) of the new token. Only the new
+    token's K, V and position are written, in place at
+    ``[layer, b, pos % W]`` (W = window for SWA, max_len for full
+    attention), so a pass writes one token per layer; the token then
+    attends that layer's slots, with entries older than the window masked
+    via stored positions. Returns (y (B, 1, D), the updated stack).
     """
     B, _, D = x.shape
-    W = cache["k"].shape[1]
+    W = cache["k"].shape[2]
     q = (x @ params["wq"])
     k = (x @ params["wk"])
     v = (x @ params["wv"])
@@ -259,17 +264,30 @@ def attn_decode_step(params, x, cache, pos, spec: AttnSpec):
 
     slot = (pos % W).astype(jnp.int32)
     bidx = jnp.arange(B)
-    new_k = cache["k"].at[bidx, slot].set(k[:, 0])
-    new_v = cache["v"].at[bidx, slot].set(v[:, 0])
-    new_pos = cache["pos"].at[bidx, slot].set(pos.astype(jnp.int32))
+    cache = {
+        "k": cache["k"].at[layer, bidx, slot].set(k[:, 0]),
+        "v": cache["v"].at[layer, bidx, slot].set(v[:, 0]),
+        "pos": cache["pos"].at[layer, bidx, slot].set(pos.astype(jnp.int32)),
+    }
 
-    kv_pos = new_pos  # (B, W) absolute positions; empty slots are -1
+    kv_pos = cache["pos"][layer]  # (B, W) absolute positions; empty: -1
     dspec = dataclasses.replace(spec, q_block=1)
     bias_valid = jnp.where(kv_pos >= 0, 0.0, NEG_INF)[:, None, :]
     bias = _mask_bias(pos[:, None], kv_pos, dspec) + bias_valid
-    out = _sdpa_block(q, new_k, new_v, bias)
+    out = _sdpa_block(q, cache["k"][layer], cache["v"][layer], bias)
     y = out.reshape(B, 1, -1) @ params["wo"]
-    return y, {"k": new_k, "v": new_v, "pos": new_pos}
+    return y, cache
+
+
+def attn_decode_step(params, x, cache, pos, spec: AttnSpec):
+    """One-token decode over one layer's ring cache {"k","v": (B, W, KV,
+    hd), "pos": (B, W)}: ``attn_decode_layer`` on a stack of one. Used
+    where attention layers do not form one scanned stack (``hybrid``'s
+    shared attention block, ``encdec``'s decoder self-attention); returns
+    (y, the layer's new cache)."""
+    y, cache = attn_decode_layer(
+        params, x, jax.tree.map(lambda a: a[None], cache), 0, pos, spec)
+    return y, jax.tree.map(lambda a: a[0], cache)
 
 
 def attn_cache_init(batch: int, width: int, spec: AttnSpec,

@@ -215,33 +215,38 @@ def decode_step(params, cfg: LMConfig, cache, tokens, pos,
                 prefix_embeds=None):
     """One decode step. tokens: (B,) int32; pos: (B,) absolute positions.
 
-    Returns (logits (B, V), new cache). The prefix mask is irrelevant at
-    decode (all cached positions are visible to the new token).
+    The stacked ring cache (leaves (L, B, W, ...)) rides in the layer
+    scan's carry and each layer writes only its new token's K, V and
+    position in place (``nn.attn_decode_layer``): one token per layer per
+    pass, never a rebuilt copy of the whole cache. Returns (logits (B, V),
+    the updated cache). The prefix mask is irrelevant at decode (all
+    cached positions are visible to the new token).
     """
-    B = tokens.shape[0]
     spec = cfg.attn_spec(prefix_len=0)
     x = params["embed"][tokens][:, None, :]
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
 
-    def body(x, scanned):
-        layer, lcache = scanned
+    def body(carry, scanned):
+        x, cache = carry
+        layer, i = scanned
         h = nn.rmsnorm(layer["ln1"], x)
-        y, new_cache = nn.attn_decode_step(layer["attn"], h, lcache, pos,
-                                           spec)
+        y, cache = nn.attn_decode_layer(layer["attn"], h, cache, i, pos,
+                                        spec)
         x = x + y
         h = nn.rmsnorm(layer["ln2"], x)
         if cfg.moe is not None:
             x = x + nn.moe_apply(layer["moe"], h, cfg.moe)
         else:
             x = x + nn.swiglu(layer["mlp"], h)
-        return x, new_cache
+        return (x, cache), None
 
-    x, new_cache = runconfig.scan(body, x, (params["layers"], cache))
+    (x, cache), _ = runconfig.scan(
+        body, (x, cache), (params["layers"], jnp.arange(cfg.num_layers)))
     x = nn.rmsnorm(params["ln_f"], x)
     logits = runconfig.constrain(_unembed(params, cfg, x[:, 0, :]),
                                  ("dp", "tp"))
-    return logits, new_cache
+    return logits, cache
 
 
 def prefill(params, cfg: LMConfig, tokens, prefix_embeds=None,

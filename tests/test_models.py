@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro import configs as configs_lib
+from repro.models import layers as nn
 from repro.models import registry as R
 from repro.models import transformer as T
 from repro.models.layers import MoESpec
@@ -201,6 +202,80 @@ class TestDecodeConsistency:
             np.asarray(full, np.float32),
             np.asarray(jnp.stack(outs, 1), np.float32),
             atol=2e-2, rtol=2e-2)
+
+
+def _per_layer_decode(params, cfg, cache, tokens, pos):
+    """The per-layer decode oracle: the layer scan takes each layer's cache
+    as ``xs`` and stacks ``nn.attn_decode_step``'s new caches as ``ys``."""
+    spec = cfg.attn_spec(prefix_len=0)
+    x = params["embed"][tokens][:, None, :]
+
+    def body(x, scanned):
+        layer, lcache = scanned
+        h = nn.rmsnorm(layer["ln1"], x)
+        y, lcache = nn.attn_decode_step(layer["attn"], h, lcache, pos, spec)
+        x = x + y
+        h = nn.rmsnorm(layer["ln2"], x)
+        if cfg.moe is not None:
+            return x + nn.moe_apply(layer["moe"], h, cfg.moe), lcache
+        return x + nn.swiglu(layer["mlp"], h), lcache
+
+    x, cache = jax.lax.scan(body, x, (params["layers"], cache))
+    x = nn.rmsnorm(params["ln_f"], x)
+    return T._unembed(params, cfg, x[:, 0, :]), cache
+
+
+def _variant(name):
+    if name == "swa_wrap":
+        return dataclasses.replace(R.build("smollm-135m", smoke=True).cfg,
+                                   window=8)
+    arch = {"gqa": "smollm-135m", "mha": "stablelm-3b",
+            "qkv_bias": "qwen2.5-14b", "moe": "mixtral-8x7b"}[name]
+    return R.build(arch, smoke=True).cfg
+
+
+class TestCarriedCacheDecode:
+    """``decode_step`` carries the stacked cache through the layer scan and
+    writes one token per layer in place; it must equal the per-layer scan
+    bit for bit, logits and all three cache leaves, on every step."""
+
+    @pytest.mark.parametrize("variant", ["gqa", "mha", "swa_wrap",
+                                         "qkv_bias", "moe"])
+    def test_bit_identical_to_per_layer_scan(self, variant):
+        cfg = _variant(variant)
+        if variant == "mha":
+            assert cfg.num_heads == cfg.num_kv_heads
+        else:
+            assert cfg.num_heads > cfg.num_kv_heads
+        params = T.init(jax.random.fold_in(KEY, 20), cfg)
+        B, cache_len, steps = 4, 32, 20
+        cache = T.init_cache(cfg, B, cache_len)
+        W = cache["k"].shape[2]
+        toks = jax.random.randint(jax.random.fold_in(KEY, 21), (steps, B),
+                                  0, cfg.vocab)
+        new = jax.jit(lambda c, t, p: T.decode_step(params, cfg, c, t, p))
+        old = jax.jit(lambda c, t, p: _per_layer_decode(params, cfg, c, t,
+                                                        p))
+        # rows start at staggered positions; row 3 moves on even steps only
+        # and otherwise is fed the megastep's dummy token at its next write
+        # position, which its next move overwrites.
+        written = np.array([0, 3, 5, 1], np.int32)
+        c_new = c_old = cache
+        for t in range(steps):
+            moving = np.array([True, True, True, t % 2 == 0])
+            tok = jnp.where(jnp.asarray(moving), toks[t], 0)
+            pos = jnp.asarray(written)
+            lg_new, c_new = new(c_new, tok, pos)
+            lg_old, c_old = old(c_old, tok, pos)
+            np.testing.assert_array_equal(np.asarray(lg_new, np.float32),
+                                          np.asarray(lg_old, np.float32))
+            for leaf in ("k", "v", "pos"):
+                np.testing.assert_array_equal(
+                    np.asarray(c_new[leaf], np.float32),
+                    np.asarray(c_old[leaf], np.float32))
+            written = written + moving
+        if variant == "swa_wrap":      # the ring wrapped more than twice
+            assert W == 8 and int(np.asarray(c_new["pos"]).max()) >= 2 * W
 
 
 class TestParamCounts:

@@ -269,6 +269,27 @@ class TestBatchedPaging:
         assert tokens
         assert all(bt <= n < W for n in tokens), tokens
 
+    @pytest.mark.parametrize("paged", [True, False],
+                             ids=["paged", "unpaged"])
+    def test_megastep_copies_no_whole_cache(self, api, params, paged):
+        """The dense K=8 megastep writes each token into the cache in
+        place: no ``copy`` in the compiled program yields a whole K or V
+        leaf, as the model branch did on every micro-step when the layer
+        scan rebuilt the cache as its stacked output."""
+        from repro.serve.engine import _fused_megastep_program
+        eng = ServeEngine(api, params, _cfg(paging=paged))
+        assert eng.paged == paged
+        leaf = "bf16[%s]" % ",".join(map(str, eng.cache["k"].shape))
+        prog = _fused_megastep_program(
+            api, eng.cfg.prefill_chunk, 8,
+            eng.cfg.block_tokens if paged else None)
+        text = prog.lower(params, eng.cache, eng._dev).compile().as_text()
+        assert leaf in text
+        copies = [line for line in text.splitlines()
+                  if re.search(r"= %s\{[^}]*\} copy\(" % re.escape(leaf),
+                               line)]
+        assert not copies, copies
+
     def test_paging_disabled_still_serves(self, api, params):
         eng = ServeEngine(api, params, _cfg(paging=False))
         prompts = jax.random.randint(jax.random.PRNGKey(4), (2, 5), 0,
