@@ -8,30 +8,11 @@ import json
 import os
 import time
 
-#: Row provenance labels: ``engine`` rows were measured on the real
-#: ServeEngine / kernels (functional execution real, link timing
-#: modelled); ``sim`` rows come from the analytical stream simulator
-#: (``core.scheduler``). run.py's CSV carries the label per row so the
-#: two are never conflated.
+#: Row provenance labels: ``engine`` rows drive the real ServeEngine /
+#: kernels (functional execution real, link timing modelled); ``sim`` rows
+#: come from the analytical stream simulator (``core.scheduler``). run.py's
+#: CSV carries the label per row so the two are never conflated.
 ENGINE, SIM = "engine", "sim"
-
-#: 1-minute loadavg per core above which wall-clock numbers are suspect
-#: (measured: concurrent pytest skews BENCH markers 3-10x).
-LOAD_THRESHOLD = 0.5
-
-
-def machine_load() -> dict:
-    """Machine-load provenance for a benchmark entry: 1-minute loadavg,
-    core count, and whether the measurement ran on a *loaded* machine
-    (wall-clock throughput markers skew 3-10x under concurrent load —
-    modelled `_us` metrics are deterministic and unaffected)."""
-    try:
-        la1 = float(os.getloadavg()[0])
-    except (OSError, AttributeError):       # platforms without loadavg
-        la1 = -1.0
-    cpus = os.cpu_count() or 1
-    return {"loadavg1": round(la1, 2), "cpus": cpus,
-            "loaded": bool(la1 >= 0 and la1 / cpus > LOAD_THRESHOLD)}
 
 
 class Bench:
@@ -97,29 +78,18 @@ def bench_json_path() -> str:
 def update_bench_json(section: str, payload: dict) -> dict:
     """Read-modify-write one workload's section of ``BENCH_serve.json``.
 
-    The file is the repo-root serving perf trajectory marker, one section
-    per workload: ``{"llm": {...}, "redis": {...}, "vectordb": {...}}``.
-    Each benchmark module owns its section; CI diffs per workload against
-    the previous CI run. A legacy flat file (pre-multi-tenant: top-level
-    ``tokens_per_s``) is migrated into the ``llm`` section on first
-    touch. Every section gets a ``load`` provenance record
-    (``machine_load``) stamped at write time, so readers — and the CI
-    perf diff — can tell which entries were measured on a loaded
-    machine.
+    The file holds the paper-figure A/Bs of the tenant and tier modules,
+    one section per module (``redis``, ``vectordb``, ``tiered``). Every
+    number in it is modelled (link time, engine steps), so it is the same
+    on any machine; serving speed is measured on the chip by
+    ``bench/run.py`` and recorded nowhere in this file.
     """
     path = bench_json_path()
     doc: dict = {}
     if os.path.exists(path):
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-        except (OSError, ValueError):
-            doc = {}
-        if "tokens_per_s" in doc:                 # legacy flat schema
-            doc = {"llm": {k: doc[k] for k in
-                           ("tokens_per_s", "steps", "duplex_speedup")
-                           if k in doc}}
-    doc[section] = dict(payload, load=machine_load())
+        with open(path) as f:
+            doc = json.load(f)
+    doc[section] = payload
     with open(path, "w") as f:
         json.dump(doc, f, indent=1, sort_keys=True)
         f.write("\n")

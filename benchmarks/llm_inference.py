@@ -7,30 +7,23 @@ Two phases, per the paper's layer traffic analysis:
     FFN (60/40) traffic, with KV paging against the host pool (paper:
     +71.6%, 1.41 -> 2.42 tok/s for DeepSeek-671B).
 
-Throughput proxy: modelled memory-time per token from (a) the policy A/B on
-the layer-traffic stream mix and (b) the duplex-vs-serial KV paging plans
-of the tiered cache. The kimi-k2 (1T) config supplies the real per-token
-byte volumes (active params + KV per layer).
+Throughput proxy: modelled memory-time per token from the policy A/B on
+the layer-traffic stream mix (``core.scheduler``). The kimi-k2 (1T) config
+supplies the real per-token byte volumes (active params). These are paper
+figures from a simulator, not serving speed: that is measured on the chip
+by ``bench/run.py``.
 """
 
 from __future__ import annotations
 
-import dataclasses
-import json
-import os
 import time
-
-import jax
-import numpy as np
 
 from repro.core import channel as ch
 from repro.core import scheduler as sched
 from repro.core.requests import StreamSpec
 from repro.models import registry as R
-from repro.serve import EngineConfig, ServeEngine
 
-from benchmarks.common import (ENGINE, SIM, Bench, out_dir,
-                               update_bench_json, write_csv)
+from benchmarks.common import SIM, Bench, write_csv
 
 
 def _decode_specs(offered: float = 60.0, n: int = 8) -> list[StreamSpec]:
@@ -52,12 +45,7 @@ def run(smoke: bool = False) -> Bench:
     b = Bench("llm_inference", provenance=SIM)
     api = R.build("kimi-k2-1t-a32b")
     bytes_per_token = api.active_param_count * 2.0     # bf16 reads
-    # smoke trims the simulator sweeps and the measured repeats; the
-    # engine row still runs (it IS the smoke target) and still updates
-    # the "llm" BENCH section — CI always runs this module full, so its
-    # baseline chain only ever sees full-mode numbers.
-    sim_steps = 256 if smoke else 768
-    repeats = 1 if smoke else 3
+    sim_steps = 256 if smoke else 768     # smoke trims the sweeps
 
     # -- prefill: withdrawal keeps it neutral ------------------------------
     t0 = time.monotonic()
@@ -82,358 +70,13 @@ def run(smoke: bool = False) -> Bench:
           f"tok/s {toks_a:.2f}->{toks_b:.2f} ({imp_d:+.1%}; "
           f"paper +71.6%: 1.41->2.42)")
 
-    # -- decode: real continuous-batching serve, KV paged through the
-    #    duplex engine on the actual request stream --------------------------
-    # REPRO_MEGASTEP picks the engine's steps-per-host-dispatch width:
-    # the default 8 is the tentpole configuration ("llm" BENCH section);
-    # CI additionally smokes 1 and 4 into their own sections so
-    # dispatch-tax regressions stay visible per width. REPRO_PIPELINE
-    # picks the boundary pipeline depth (default 2 — double-buffered
-    # dispatch); when set explicitly the run lands in its own
-    # "llm_pipe<d>" section so CI can diff depth 2 against depth 1.
-    megastep = int(os.environ.get("REPRO_MEGASTEP", "8"))
-    pipe_env = os.environ.get("REPRO_PIPELINE")
-    pipeline = int(pipe_env) if pipe_env else 2
-    api_s = R.build("smollm-135m", smoke=True)
-    params = api_s.init(jax.random.PRNGKey(0))
-    ecfg = EngineConfig(max_batch=4, cache_len=64, block_tokens=4,
-                        hbm_blocks=6, prefill_chunk=2, max_queue=8,
-                        megastep=megastep, pipeline_depth=pipeline)
-
-    def _drive(eng: ServeEngine):
-        key = jax.random.PRNGKey(1)
-        for i in range(6):
-            prompt = jax.random.randint(jax.random.fold_in(key, i), (6,),
-                                        0, api_s.cfg.vocab)
-            eng.submit(np.asarray(prompt), 12, arrival_step=2 * i)
-        t0 = time.monotonic()     # time the serving loop, not build/init
-        outs = eng.run()
-        return outs, time.monotonic() - t0
-
-    # warmup: the first run compiles the fused step / paging / admission
-    # programs; they are cached per (ModelAPI, config) cell, so the
-    # measured engines below reuse them and the row reports steady-state
-    # serving throughput, not XLA compile time. Best-of-3 measured runs
-    # (the whole run is ~100ms; best-of de-noises shared-machine load).
-    _warm_outs, warm_dt = _drive(ServeEngine(api_s, params, ecfg))
-    best = None
-    for _ in range(repeats):
-        eng = ServeEngine(api_s, params, ecfg)
-        outs, dt = _drive(eng)
-        if best is None or dt < best[1]:
-            best = (eng, dt, outs)
-    eng, dt, outs = best
-    st = eng.paging_stats()
-    tokens = sum(len(v) for v in outs.values())
-    tok_s = tokens / dt
-    # gap-to-ceiling: the same fused megastep cell driven with zero host
-    # work between dispatches is the device-side roof; roofline_frac is
-    # the fraction of it the full serving loop (admission, planning,
-    # paging, readbacks) actually delivers — the number the pipelined
-    # dispatcher moves.
-    from benchmarks.roofline import serve_kernel_ceiling
-    ceiling = serve_kernel_ceiling(api_s, params, ecfg,
-                                   repeats=1 if smoke else 3)
-    frac = tok_s / ceiling if ceiling > 0 else 0.0
-    b.row("decode/kv-paging", dt * 1e6,
-          f"steady {tok_s:.0f} tok/s = {frac:.0%} of the "
-          f"{ceiling:.0f} tok/s kernel ceiling (warmup {warm_dt:.2f}s); "
-          f"megastep={megastep} pipeline={pipeline}: "
-          f"{st['host_dispatches']} dispatches/"
-          f"{eng.step_count} steps/{st['host_blocked']} blocked; "
-          f"duplex_speedup={st['duplex_speedup']:.2f}x "
-          f"({st['page_ins']} ins/{st['page_outs']} outs; "
-          f"{st['kernel_calls']} kernel calls; "
-          f"{tokens} tok served)", provenance=ENGINE)
-
-    # the repo-root perf trajectory marker: "llm" section at the default
-    # megastep width, "llm_megastep<K>" for the CI dispatch-tax smokes,
-    # "llm_pipe<d>" when REPRO_PIPELINE pins the pipeline depth (the CI
-    # depth-2-vs-depth-1 A/B). CI diffs each workload's section against
-    # the previous CI run and warns on >20% regression; host_dispatches
-    # and host_blocked ride along so dispatch-tax and pipeline-bubble
-    # regressions stay visible even when tokens/s noise hides them.
-    if pipe_env is not None:
-        section = f"llm_pipe{pipeline}"
-    elif megastep != 8:
-        section = f"llm_megastep{megastep}"
-    elif (os.environ.get("REPRO_FAULTS") or os.environ.get("REPRO_SHARD")
-          or os.environ.get("REPRO_SNAPSHOT")
-          or os.environ.get("REPRO_TRACE")):
-        # the fault, shard, snapshot, and trace smokes run in smoke mode
-        # at the default width: their single-device untraced row must not
-        # clobber the full-mode "llm" baseline — only the "llm_faults"/
-        # "llm_shard<N>"/"llm_snapshot"/"llm_trace" sections below belong
-        # to them.
-        section = None
-    else:
-        section = "llm"
-    if section is not None:
-        # traced twin: a non-measured re-run with the tracer attached
-        # supplies the per-phase boundary breakdown and the per-channel
-        # duplex utilization for the BENCH section; tokens are asserted
-        # bit-exact against the measured untraced run above — the
-        # benchmark-level echo of the zero-cost-when-disabled contract.
-        from repro.serve import Tracer
-        twin = Tracer()
-        t_eng = ServeEngine(api_s, params,
-                            dataclasses.replace(ecfg, trace=twin))
-        outs_t, _ = _drive(t_eng)
-        for a, b_ in zip((outs[r] for r in sorted(outs)),
-                         (outs_t[r] for r in sorted(outs_t))):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
-        phase = twin.phase_totals()
-        update_bench_json(section, {
-            "tokens_per_s": round(tok_s, 1),
-            "steps": int(eng.step_count),
-            "megastep": megastep,
-            "pipeline_depth": pipeline,
-            "host_dispatches": int(st["host_dispatches"]),
-            "host_blocked": int(st["host_blocked"]),
-            "kernel_ceiling_tok_s": round(ceiling, 1),
-            "roofline_frac": round(frac, 4),
-            "duplex_speedup": round(st["duplex_speedup"], 4),
-            "phase_us": {k: round(phase.get(f"{k}_us", 0.0), 1)
-                         for k in ("plan", "dispatch", "reconcile")},
-            "duplex_util": {t: round(u["util"], 4)
-                            for t, u in twin.duplex_util().items()}})
-
-    # -- fault-matrix smoke: REPRO_FAULTS=1 re-runs the serve row under
-    # a transient + channel-offline + poisoned-block plan on a tiered
-    # host pool and asserts graceful degradation end-to-end — the run
-    # completes, recovery actually happened (nonzero recovered and
-    # evacuated counters), survivors produced tokens, and the pool's
-    # invariants held. Lands in its own "llm_faults" BENCH section so
-    # the chaos path has a CI trajectory of its own.
-    if os.environ.get("REPRO_FAULTS"):
-        from repro.core.faults import FaultInjector, parse_fault_plan
-        plan = "transient:0@2+40=0.4,offline:2@10,poison:0@6,poison:1@7"
-        fx_eng = ServeEngine(api_s, params, dataclasses.replace(
-            ecfg, tiers="ddr5:1,cxl:2",
-            faults=FaultInjector(parse_fault_plan(plan), seed=0)))
-        outs_f, dt_f = _drive(fx_eng)
-        f = fx_eng.stats()["faults"]
-        served_f = sum(len(v) for v in outs_f.values())
-        assert outs_f, "fault smoke: no survivors"
-        assert f["recovered"] > 0, "fault smoke: nothing recovered"
-        assert f["evacuated"] > 0, \
-            "fault smoke: offline evacuation did not run"
-        fx_eng.pool.check_invariants()
-        b.row("decode/fault-matrix", dt_f * 1e6,
-              f"plan [{plan}]: {f['injected']} injected, "
-              f"{f['recovered']} recovered, {f['evacuated']} evacuated, "
-              f"{f['quarantined']} quarantined, {f['shed']} shed, "
-              f"{len(fx_eng.failed)} failed reqs; {served_f} tok from "
-              f"survivors", provenance=ENGINE)
-        update_bench_json("llm_faults", {
-            "plan": plan,
-            "tokens_served": int(served_f),
-            "injected": int(f["injected"]),
-            "recovered": int(f["recovered"]),
-            "evacuated": int(f["evacuated"]),
-            "quarantined": int(f["quarantined"]),
-            "shed": int(f["shed"]),
-            "failed_requests": len(fx_eng.failed),
-            "retry_us": round(f["retry_us"], 3)})
-
-    # -- snapshot/restore smoke: REPRO_SNAPSHOT=1 measures the crash-
-    # consistency tax (tok/s with snapshot_every=8 cuts + WAL vs the
-    # disabled run above — the "llm_snapshot" BENCH schema: tokens_per_s
-    # is the snapshot-enabled number, overhead_frac the relative cost CI
-    # warns about above 5%), then kills a run at a fixed pool
-    # transaction, restores from the newest cut, and diffs the resumed
-    # transcript token-for-token against the uncrashed reference.
-    if os.environ.get("REPRO_SNAPSHOT"):
-        import shutil
-        import tempfile
-
-        from repro.core.faults import (CrashFault, FaultInjector,
-                                       parse_fault_plan)
-        snap_root = tempfile.mkdtemp(prefix="bench_snap_")
-        try:
-            scfg = dataclasses.replace(
-                ecfg, snapshot_every=8,
-                snapshot_dir=os.path.join(snap_root, "warm"))
-            _drive(ServeEngine(api_s, params, scfg))    # warm flush path
-            s_eng = ServeEngine(api_s, params, dataclasses.replace(
-                scfg, snapshot_dir=os.path.join(snap_root, "measure")))
-            outs_sn, dt_sn = _drive(s_eng)
-            for a, b_ in zip((outs[r] for r in sorted(outs)),
-                             (outs_sn[r] for r in sorted(outs_sn))):
-                np.testing.assert_array_equal(np.asarray(a),
-                                              np.asarray(b_))
-            snaps = s_eng.stats()["snapshot"]
-            assert snaps["snapshots_taken"] > 0, \
-                "snapshot smoke: no cuts taken"
-            tok_sn = sum(len(v) for v in outs_sn.values()) / dt_sn
-            overhead = max(0.0, 1.0 - tok_sn / tok_s) if tok_s else 0.0
-
-            # crash at a fixed transaction, restore, diff the transcript
-            crash_d = os.path.join(snap_root, "crash")
-            ccfg = dataclasses.replace(
-                ecfg, snapshot_every=2, snapshot_dir=crash_d,
-                faults=FaultInjector(parse_fault_plan("crash:@11")))
-            try:
-                _drive(ServeEngine(api_s, params, ccfg))
-                raise AssertionError("snapshot smoke: crash never fired")
-            except CrashFault:
-                pass
-            r_eng = ServeEngine(api_s, params, dataclasses.replace(
-                ccfg, faults=FaultInjector(parse_fault_plan("crash:@11"))))
-            info = r_eng.restore()
-            r_eng.run()
-            for a, b_ in zip((outs[r] for r in sorted(outs)),
-                             (r_eng.completed[r].generated
-                              for r in sorted(r_eng.completed))):
-                np.testing.assert_array_equal(np.asarray(a),
-                                              np.asarray(b_))
-            assert len(r_eng.completed) == len(outs), \
-                "snapshot smoke: restore lost requests"
-            b.row("decode/snapshot-restore", dt_sn * 1e6,
-                  f"every=8: {tok_sn:.0f} tok/s "
-                  f"({overhead:.1%} overhead vs disabled), "
-                  f"{snaps['snapshots_taken']} cuts/"
-                  f"{snaps['journal_entries']} journal entries; "
-                  f"crash@11 restored from cut {info['restored_step']}, "
-                  f"{info['pending_resubmits']} resubmits, transcript "
-                  f"bit-exact", provenance=ENGINE)
-            update_bench_json("llm_snapshot", {
-                "tokens_per_s": round(tok_sn, 1),
-                "tokens_per_s_disabled": round(tok_s, 1),
-                "overhead_frac": round(overhead, 4),
-                "snapshot_every": 8,
-                "snapshots_taken": int(snaps["snapshots_taken"]),
-                "journal_entries": int(snaps["journal_entries"]),
-                "restored_step": int(info["restored_step"]),
-                "restore_replayed": int(
-                    r_eng.stats()["snapshot"]["restore_replayed"]),
-                "restore_bit_exact": True})
-        finally:
-            shutil.rmtree(snap_root, ignore_errors=True)
-
-    # -- sharded-serving smoke: REPRO_SHARD=<N> re-runs the serve row on
-    # a data × model mesh over N (forced-host) devices and
-    # differential-asserts the tokens against the single-device run
-    # above — the benchmark-level echo of tests/test_shard_serve.py.
-    # Lands in "llm_shard<N>" with tok/s, roofline_frac and per-link ICI
-    # bytes so the sharded path gets its own CI perf trajectory.
-    if os.environ.get("REPRO_SHARD"):
-        from repro.launch.mesh import make_debug_mesh
-        from repro.serve import ShardedServeEngine
-        n_dev = min(int(os.environ["REPRO_SHARD"]), len(jax.devices()))
-        model = 2 if n_dev % 2 == 0 else 1
-        mesh = make_debug_mesh(model, devices=jax.devices()[:n_dev])
-        _drive(ShardedServeEngine(api_s, params, ecfg, mesh=mesh))  # warm
-        s_eng = ShardedServeEngine(api_s, params, ecfg, mesh=mesh)
-        outs_s, dt_s = _drive(s_eng)
-        for a, b_ in zip((outs[r] for r in sorted(outs)),
-                         (outs_s[r] for r in sorted(outs_s))):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
-        if s_eng.pool is not None:
-            s_eng.pool.check_invariants()
-        st_s = s_eng.paging_stats()
-        ici = st_s["ici"]
-        links = {p.rsplit("/", 1)[1]: round(q["bytes"], 1)
-                 for p, q in st_s["by_path"].items()
-                 if p.startswith("/serve/ici/")}
-        tokens_s = sum(len(v) for v in outs_s.values())
-        tok_s_sh = tokens_s / dt_s
-        frac_sh = tok_s_sh / ceiling if ceiling > 0 else 0.0
-        b.row("decode/sharded", dt_s * 1e6,
-              f"mesh {st_s['mesh']['data']}x{st_s['mesh']['model']} over "
-              f"{n_dev} devices: {tok_s_sh:.0f} tok/s = {frac_sh:.0%} of "
-              f"single-device ceiling, bit-exact with the 1-device run; "
-              f"ici {ici['bytes']:.0f} B / {ici['collectives']} "
-              f"collectives ({links})", provenance=ENGINE)
-        update_bench_json(f"llm_shard{n_dev}", {
-            "tokens_per_s": round(tok_s_sh, 1),
-            "mesh_data": st_s["mesh"]["data"],
-            "mesh_model": st_s["mesh"]["model"],
-            "megastep": megastep,
-            "pipeline_depth": pipeline,
-            "kernel_ceiling_tok_s": round(ceiling, 1),
-            "roofline_frac": round(frac_sh, 4),
-            "ici_bytes": round(ici["bytes"], 1),
-            "ici_collectives": int(ici["collectives"]),
-            "ici_duplex_us": round(ici["duplex_us"], 3),
-            "ici_bytes_per_link": links})
-
-    # -- trace smoke: REPRO_TRACE=1 re-runs the serve row on a tiered
-    # pool twice — untraced baseline, then traced — asserts the traced
-    # run is token-bit-exact, exports the Perfetto trace next to the
-    # other bench artifacts, validates it (JSON loads; plan/dispatch/
-    # reconcile spans present; ddr5+cxl channel tracks present; every
-    # track's intervals monotonic and non-overlapping), and records the
-    # tracing overhead vs the untraced baseline in its own "llm_trace"
-    # section (CI warns above 3% on an unloaded runner).
-    if os.environ.get("REPRO_TRACE"):
-        from repro.serve import Tracer
-        tcfg = dataclasses.replace(ecfg, tiers="ddr5:1,cxl:2")
-        _drive(ServeEngine(api_s, params, tcfg))    # warm tiered paging
-        outs_u, dt_u = _drive(ServeEngine(api_s, params, tcfg))
-        trace_path = os.path.join(out_dir(), "llm_trace.json")
-        tr = Tracer(path=trace_path)
-        tr_eng = ServeEngine(api_s, params,
-                             dataclasses.replace(tcfg, trace=tr))
-        outs_tr, dt_tr = _drive(tr_eng)
-        for a, b_ in zip((outs_u[r] for r in sorted(outs_u)),
-                         (outs_tr[r] for r in sorted(outs_tr))):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
-        tr_eng.export_trace()
-        with open(trace_path) as f:
-            doc = json.load(f)
-        span_names = {e["name"] for e in doc["traceEvents"]
-                      if e.get("ph") == "X"}
-        assert {"plan", "dispatch", "reconcile"} <= span_names, span_names
-        tracks = sorted(tr.timelines)
-        assert any(t.startswith("ddr5:") for t in tracks), tracks
-        assert any(t.startswith("cxl:") for t in tracks), tracks
-        for ivs in tr.timelines.values():
-            end = 0.0
-            for iv_t0, iv_dur, _n, _a in ivs:
-                assert iv_t0 >= end - 1e-6, "overlapping trace intervals"
-                end = iv_t0 + iv_dur
-        tok_u = sum(len(v) for v in outs_u.values()) / dt_u
-        tok_tr = sum(len(v) for v in outs_tr.values()) / dt_tr
-        overhead_tr = max(0.0, 1.0 - tok_tr / tok_u) if tok_u else 0.0
-        phase_tr = tr.phase_totals()
-        util_tr = tr.duplex_util()
-        b.row("decode/trace", dt_tr * 1e6,
-              f"traced {tok_tr:.0f} vs untraced {tok_u:.0f} tok/s "
-              f"({overhead_tr:+.1%} overhead); "
-              f"{len(doc['traceEvents'])} events, {len(tracks)} channel "
-              f"tracks, plan {phase_tr.get('plan_us', 0.0):.0f}us / "
-              f"dispatch {phase_tr.get('dispatch_us', 0.0):.0f}us / "
-              f"reconcile {phase_tr.get('reconcile_us', 0.0):.0f}us; "
-              f"bit-exact with untraced", provenance=ENGINE)
-        update_bench_json("llm_trace", {
-            "tokens_per_s": round(tok_tr, 1),
-            "tokens_per_s_untraced": round(tok_u, 1),
-            "overhead_frac": round(overhead_tr, 4),
-            "trace_events": len(doc["traceEvents"]),
-            "channel_tracks": len(tracks),
-            "model_us": round(tr.model_us, 3),
-            "phase_us": {k: round(phase_tr.get(f"{k}_us", 0.0), 1)
-                         for k in ("plan", "dispatch", "reconcile")},
-            "duplex_util": {t: round(u["util"], 4)
-                            for t, u in util_tr.items()},
-            "trace_bit_exact": True})
-
     write_csv("fig6_llm.csv",
               ["phase", "cfs_gbps", "cxlaimpod_gbps", "improvement"],
               [["prefill", round(res_p["cfs"]["gbps"], 2),
                 round(res_p["hinted"]["gbps"], 2), round(imp_p, 4)],
                ["decode", round(res_d["cfs"]["gbps"], 2),
                 round(res_d["hinted"]["gbps"], 2), round(imp_d, 4)]])
-    write_csv("fig6_kv_paging.csv",
-              ["page_ins", "page_outs", "kernel_calls", "engine_steps",
-               "duplex_us", "serial_us", "duplex_speedup"],
-              [[st["page_ins"], st["page_outs"], st["kernel_calls"],
-                eng.step_count, round(st["duplex_us"], 3),
-                round(st["serial_us"], 3),
-                round(st["duplex_speedup"], 4)]])
-    return b.done(f"prefill={imp_p:+.1%} decode={imp_d:+.1%} "
-                  f"kv_paging={st['duplex_speedup']:.2f}x")
+    return b.done(f"prefill={imp_p:+.1%} decode={imp_d:+.1%}")
 
 
 if __name__ == "__main__":

@@ -79,9 +79,7 @@ def _drive(api, params, pattern: str, policy: str, n_streams: int,
         # schedule constants.
         kv.submit(pattern, n_steps=6 * steps, n_ops=steps,
                   arrival_step=0, phase=phase)
-    t0 = time.monotonic()
     eng.run(max_steps=10_000)
-    dt = time.monotonic() - t0
     link = aggregate_link_stats(eng.paging_stats(), "/serve/redis")
     # latency: mean queue-to-completion residency in engine steps
     # (arrival -> done), the serving analogue of the paper's p99 story —
@@ -89,7 +87,7 @@ def _drive(api, params, pattern: str, policy: str, n_streams: int,
     done = list(kv.completed.values())
     lat = (sum(r.done_step - r.arrival_step for r in done)
            / max(len(done), 1))
-    return {"ops": kv.ops_done, "wall_s": dt, "link": link,
+    return {"ops": kv.ops_done, "link": link,
             "latency_steps": lat,
             "host_dispatches": eng.stats()["host_dispatches"],
             "steps": eng.step_count,
@@ -103,11 +101,6 @@ def run(smoke: bool = False) -> Bench:
     n_streams = 4 if smoke else 6
     api = R.build("smollm-135m", smoke=True)
     params = api.init(jax.random.PRNGKey(0))
-    # warmup mirrors a measured drive per policy cell (the llm
-    # benchmark's convention) so the per-pattern rows below measure
-    # steady-state serving, not XLA compile time
-    for policy in ("cfs", "hinted"):
-        _drive(api, params, "gaussian", policy, n_streams, steps)
     rows = []
     section = {}
     imps = []
@@ -119,7 +112,6 @@ def run(smoke: bool = False) -> Bench:
                for policy in ("cfs", "hinted")}
         us = (time.monotonic() - t0) * 1e6
         h, c = res["hinted"], res["cfs"]
-        mops = h["ops"] / max(h["wall_s"], 1e-9) / 1e6
         # bandwidth-normalized A/B: each policy's modelled effective link
         # bandwidth is (bytes moved / duplex-planned time), i.e. its
         # serial/duplex speedup — how much of the full-duplex channel the
@@ -131,18 +123,17 @@ def run(smoke: bool = False) -> Bench:
             / max(c["latency_steps"], 1e-9)
         imps.append(imp)
         lat_imps.append(lat_imp)
-        rows.append([pattern, round(mops, 3), round(c["speedup"], 4),
+        rows.append([pattern, h["ops"], round(c["speedup"], 4),
                      round(h["speedup"], 4), round(imp, 4),
                      round(c["latency_steps"], 1),
                      round(h["latency_steps"], 1),
                      h["link"]["page_ins"], h["link"]["page_outs"]])
-        section[pattern] = {"mops": round(mops, 3),
-                            "duplex_speedup": round(h["speedup"], 4),
+        section[pattern] = {"duplex_speedup": round(h["speedup"], 4),
                             "link_imp": round(imp, 4),
                             "latency_steps": round(h["latency_steps"], 1),
                             "latency_imp": round(lat_imp, 4)}
         b.row(pattern, us,
-              f"{h['ops']} ops {mops:.2f} Mops/s; duplex_speedup "
+              f"{h['ops']} ops; duplex_speedup "
               f"cfs {c['speedup']:.2f}x -> hinted {h['speedup']:.2f}x "
               f"({imp:+.1%}; paper {PAPER_THROUGHPUT[pattern]:+.0%}); "
               f"latency {c['latency_steps']:.0f}->"
@@ -151,7 +142,7 @@ def run(smoke: bool = False) -> Bench:
               f"{h['link']['page_outs']} outs")
     update_bench_json("redis", section)
     write_csv("fig5_redis.csv",
-              ["pattern", "hinted_mops", "cfs_duplex_speedup",
+              ["pattern", "hinted_ops", "cfs_duplex_speedup",
                "hinted_duplex_speedup", "improvement",
                "cfs_latency_steps", "hinted_latency_steps", "page_ins",
                "page_outs"], rows)

@@ -12,14 +12,6 @@ the useful-compute ratio MODEL_FLOPS/HLO_FLOPs, the dominant term, the
 bound-MFU (useful compute time / dominant term), and a rule-based
 what-would-move-it note.
 
-It also measures the *serving kernel ceiling* (``serve_kernel_ceiling``):
-the tok/s of the bare fused megastep program driven back-to-back on a
-full all-DECODE batch with zero host work between dispatches — the
-device-side roof the serving loop's measured steady-state tok/s is
-reported against (``roofline_frac`` in the llm BENCH sections), so
-pipeline/dispatcher progress is tracked as gap-to-ceiling rather than
-raw throughput alone.
-
 Methodology notes (also in EXPERIMENTS.md):
   * cost_analysis() describes the per-device SPMD module — global FLOPs =
     per-device × n_devices; the spec's formula FLOPs/(chips×peak) therefore
@@ -38,13 +30,12 @@ import json
 import os
 import time
 
-import jax
-import jax.numpy as jnp
+from bench.peaks import PEAKS
 
-from benchmarks.common import ENGINE, Bench, write_csv
+from benchmarks.common import Bench, write_csv
 
-PEAK_FLOPS = 197e12        # bf16 / chip (v5e)
-HBM_BW = 819e9             # bytes/s / chip
+PEAK_FLOPS = PEAKS["TPU v5 lite"]["bf16_flops"]
+HBM_BW = PEAKS["TPU v5 lite"]["hbm_bytes_s"]
 LINK_BW = 50e9             # bytes/s / link (ICI, per direction)
 
 DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments",
@@ -107,60 +98,6 @@ def load_all(mesh: str | None = None) -> list[dict]:
     return out
 
 
-def serve_kernel_ceiling(api, params, ecfg, *, repeats: int = 3) -> float:
-    """Measured tok/s roof of the serving engine's fused megastep kernel.
-
-    Dispatches the exact ``_fused_megastep_program`` cell the engine
-    would use — same (ModelAPI, prefill_chunk, K, block_tokens), staging
-    extraction included — back-to-back on a full all-DECODE batch with
-    *zero* host work between dispatches: no admission, no trajectory
-    planning, no paging transactions, no readbacks until the single
-    final block. Donated buffers chain every dispatch, so the result is
-    what the device alone sustains; measured serving tok/s divided by
-    this is ``roofline_frac`` — the fraction of the kernel roof the
-    host-side dispatcher actually delivers. Rounds are capped so the
-    decode cursor never runs past the ring depth (positions stay in the
-    regime real requests use). Returns best-of-``repeats`` tok/s.
-    """
-    from repro.serve.engine import _fused_megastep_program
-    from repro.serve.queue import S_DECODE
-
-    k = max(1, ecfg.megastep)
-    bt = ecfg.block_tokens if ecfg.paging else None
-    fn = _fused_megastep_program(api, ecfg.prefill_chunk, k, bt)
-    B, W = ecfg.max_batch, ecfg.cache_len
-    rounds = max(1, (W - 2) // k)
-
-    def fresh():
-        cache = api.init_cache(B, W)
-        dev = {
-            "state": jnp.full((B,), S_DECODE, jnp.int32),
-            "tok": jnp.ones((B,), jnp.int32),
-            "consumed": jnp.ones((B,), jnp.int32),
-            "n_gen": jnp.ones((B,), jnp.int32),
-            "prompt_len": jnp.ones((B,), jnp.int32),
-            "max_new": jnp.full((B,), 1 << 20, jnp.int32),  # never DONE
-            "prompt": jnp.zeros((B, W), jnp.int32),
-        }
-        return cache, dev
-
-    cache, dev = fresh()
-    out = fn(params, cache, dev)               # compile + warm the cell
-    jax.block_until_ready(out[2])
-    best = None
-    for _ in range(repeats):
-        cache, dev = fresh()
-        t0 = time.monotonic()
-        for _ in range(rounds):
-            out = fn(params, cache, dev)
-            cache, dev = out[0], out[1]
-        jax.block_until_ready(out[2])          # one sync, at the end
-        dt = time.monotonic() - t0
-        if best is None or dt < best:
-            best = dt
-    return B * k * rounds / best
-
-
 def run(smoke: bool = False) -> Bench:
     b = Bench("roofline")
     t0 = time.monotonic()
@@ -188,25 +125,7 @@ def run(smoke: bool = False) -> Bench:
               f"{worst['arch']}×{worst['shape']}: "
               f"mfu_bound={worst['mfu_bound']:.3f} ({worst['dominant']})")
 
-    # -- serving kernel ceiling: the roof the llm sections' measured
-    #    tok/s is expressed against (roofline_frac) -------------------------
-    from repro.models import registry as R
-    from repro.serve import EngineConfig
-    api = R.build("smollm-135m", smoke=True)
-    params = api.init(jax.random.PRNGKey(0))
-    ecfg = EngineConfig(max_batch=4, cache_len=64, block_tokens=4,
-                        hbm_blocks=6, prefill_chunk=2, max_queue=8,
-                        megastep=8)      # the llm bench's engine shape
-    t0 = time.monotonic()
-    ceiling = serve_kernel_ceiling(api, params, ecfg,
-                                   repeats=1 if smoke else 3)
-    us = (time.monotonic() - t0) * 1e6
-    b.row("serve/kernel-ceiling", us,
-          f"{ceiling:.0f} tok/s — bare fused K={ecfg.megastep} megastep "
-          f"program, full DECODE batch, zero host work between "
-          f"dispatches", provenance=ENGINE)
-    return b.done(f"{len(rows)} cells -> experiments/bench/roofline.csv; "
-                  f"serve kernel ceiling {ceiling:.0f} tok/s")
+    return b.done(f"{len(rows)} cells -> experiments/bench/roofline.csv")
 
 
 if __name__ == "__main__":

@@ -1,11 +1,12 @@
-"""Benchmark driver — one module per paper table/figure.
+"""Paper-figure runner — one module per paper table/figure.
 
 Prints ``name,provenance,us_per_call,derived`` CSV rows (run.py contract)
 and writes per-figure CSVs under experiments/bench/. The ``provenance``
-column separates real-engine measurements (``engine``: ServeEngine /
+column separates rows that drive the real engine (``engine``: ServeEngine /
 Pallas kernels; functional execution real, link timing modelled) from
-analytical stream-simulator numbers (``sim``: ``core.scheduler``) — the
-redis/vectordb figures are engine rows since the multi-tenant rewrite.
+analytical stream-simulator numbers (``sim``: ``core.scheduler``). The
+figures are modelled; serving speed is measured on the chip by
+``bench/run.py`` (see ``BENCHMARK.json`` and ``PERF.md``).
 
   PYTHONPATH=src python -m benchmarks.run [--only characterization,...]
                                           [--smoke]
@@ -18,7 +19,7 @@ import inspect
 import sys
 import traceback
 
-from benchmarks.common import LOAD_THRESHOLD, machine_load, out_dir
+from benchmarks.common import out_dir
 from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = ("characterization", "microbench", "redis_like",
@@ -43,19 +44,6 @@ def main() -> int:
     # create experiments/bench/ up front so a missing output directory can
     # never surface as a module failure mid-run.
     out_dir()
-
-    # wall-clock provenance: every BENCH_serve.json entry records the
-    # machine load it was measured under; warn up front when this run is
-    # already compromised (concurrent load skews wall-clock markers
-    # 3-10x — modelled `_us` metrics are unaffected).
-    load = machine_load()
-    if load["loaded"]:
-        print(f"WARNING: measuring on a loaded machine "
-              f"(loadavg1={load['loadavg1']} over {load['cpus']} cores "
-              f"> {LOAD_THRESHOLD}/core): wall-clock throughput rows "
-              f"(tok/s, mops, qps) can skew 3-10x; entries are stamped "
-              f"with this provenance in BENCH_serve.json",
-              file=sys.stderr)
 
     failed: list[str] = []
     print("name,provenance,us_per_call,derived")

@@ -34,16 +34,14 @@ def _drive(api, params, policy: str, n_requests: int, steps: int) -> dict:
         load_per_step=2, result_every=4))
     for i in range(n_requests):
         vec.submit(n_steps=steps, arrival_step=2 * i)
-    t0 = time.monotonic()
     eng.run(max_steps=10_000)
-    dt = time.monotonic() - t0
     link = aggregate_link_stats(eng.paging_stats(), "/serve/vectordb")
     res = vec.result()
     # latency proxy: mean queue-to-completion residency in engine steps.
     done = list(vec.completed.values())
     lat = (sum(r.done_step - r.arrival_step for r in done)
            / max(len(done), 1))
-    return {"queries": vec.queries_done, "wall_s": dt, "link": link,
+    return {"queries": vec.queries_done, "link": link,
             "latency_steps": lat,
             "speedup": (link["serial_us"] / link["duplex_us"]
                         if link["duplex_us"] else 1.0),
@@ -56,44 +54,32 @@ def run(smoke: bool = False) -> Bench:
     n_requests = 2 if smoke else 4
     api = R.build("smollm-135m", smoke=True)
     params = api.init(jax.random.PRNGKey(0))
-    # warmup mirrors the measured workload exactly, once per policy cell
-    # (the llm benchmark's convention): every program the run needs —
-    # engine, tenant, each policy's schedule/update/fold, each paging
-    # shape combo — compiles here and is reused from the module-level
-    # caches, so the measured drives below report steady-state serving
-    # for BOTH sides of the A/B
-    for policy in ("cfs", "hinted"):
-        _drive(api, params, policy, n_requests, steps)
     t0 = time.monotonic()
     res = {policy: _drive(api, params, policy, n_requests, steps)
            for policy in ("cfs", "hinted")}
     us = (time.monotonic() - t0) * 1e6
     h, c = res["hinted"], res["cfs"]
-    qps = h["queries"] / max(h["wall_s"], 1e-9)
     imp = h["speedup"] / c["speedup"] - 1.0
     lat_imp = (c["latency_steps"] - h["latency_steps"]) \
         / max(c["latency_steps"], 1e-9)
     b.row("hnsw-search", us,
-          f"{h['queries']} queries {qps:.0f} QPS; duplex_speedup "
+          f"{h['queries']} queries; duplex_speedup "
           f"cfs {c['speedup']:.2f}x -> hinted {h['speedup']:.2f}x "
           f"({imp:+.1%}; paper +9.1%); latency "
           f"{c['latency_steps']:.0f}->{h['latency_steps']:.0f} steps "
           f"({lat_imp:+.1%}; paper -8.3%); {h['link']['page_ins']} ins/"
           f"{h['link']['page_outs']} outs")
     update_bench_json("vectordb", {
-        "qps": round(qps, 1),
         "duplex_speedup": round(h["speedup"], 4),
         "link_imp": round(imp, 4),
         "latency_steps": round(h["latency_steps"], 1)})
     write_csv("fig7_vectordb.csv",
               ["metric", "cfs", "cxlaimpod", "improvement"],
-              [["qps", round(c["queries"] / max(c["wall_s"], 1e-9)),
-                round(qps), round(imp, 4)],
-               ["duplex_speedup", round(c["speedup"], 4),
+              [["duplex_speedup", round(c["speedup"], 4),
                 round(h["speedup"], 4), round(imp, 4)],
                ["latency_steps", round(c["latency_steps"], 1),
                 round(h["latency_steps"], 1), round(lat_imp, 4)]])
-    return b.done(f"qps={qps:.0f} duplex_speedup={h['speedup']:.2f}x "
+    return b.done(f"duplex_speedup={h['speedup']:.2f}x "
                   f"(paper +9.1%)")
 
 

@@ -35,8 +35,9 @@ class MemoryHint:
       duplex_opt_in: scopes may opt out of duplex intervention entirely
         (the paper's answer to the Redis read-heavy regression).
       tier: host-memory tier preference for this scope's spilled blocks
-        ("ddr5" | "cxl"); None = derive from the traffic mix at placement
-        time (``preferred_tier``).
+        ("ddr5" | "cxl"). A tier set on the path wins; otherwise
+        ``resolved()`` derives one from the resolved traffic mix at the
+        leaf, and a derived tier is never inherited.
     """
 
     read_fraction: float | None = None
@@ -66,8 +67,12 @@ class MemoryHint:
         return MemoryHint(**values)
 
     def resolved(self) -> "MemoryHint":
-        """Fill remaining unset fields with system defaults."""
-        return self.merged_over(SYSTEM_DEFAULT)
+        """Fill remaining unset fields with system defaults, and an unset
+        ``tier`` with the one the resolved traffic mix asks for."""
+        h = self.merged_over(SYSTEM_DEFAULT)
+        if h.tier is not None:
+            return h
+        return dataclasses.replace(h, tier=_derived_tier(h))
 
 
 SYSTEM_DEFAULT = MemoryHint(read_fraction=0.5, sequential=False,
@@ -75,24 +80,25 @@ SYSTEM_DEFAULT = MemoryHint(read_fraction=0.5, sequential=False,
                             duplex_opt_in=True)
 
 
-def preferred_tier(hint: MemoryHint) -> str:
-    """Host-tier preference for a scope's spilled blocks (§3 placement).
-
-    An explicit ``tier`` wins. Otherwise derive from the traffic mix:
-    mixed read/write scopes belong on full-duplex CXL channels, where
-    their opposing directions overlap; unidirectional (read- or
-    write-mostly, past the ~4:1 point where the paper's withdrawal
-    doctrine kicks in) and duplex-withdrawn scopes gain nothing from
-    duplexing and go to the low-latency half-duplex DDR5 channels,
-    which serve a single direction at full rate with no turnaround tax.
-    """
-    h = hint.resolved()
-    if hint.tier is not None:
-        return hint.tier
+def _derived_tier(h: MemoryHint) -> str:
+    """The §3 placement rule for a scope that names no tier: mixed
+    read/write scopes belong on full-duplex CXL channels, where their
+    opposing directions overlap; unidirectional (read- or write-mostly,
+    past the ~4:1 point where the paper's withdrawal doctrine kicks in)
+    and duplex-withdrawn scopes gain nothing from duplexing and go to the
+    low-latency half-duplex DDR5 channels, which serve a single direction
+    at full rate with no turnaround tax. ``h`` has every other field
+    resolved."""
     if h.duplex_opt_in is False:
         return "ddr5"
-    rf = 0.5 if h.read_fraction is None else float(h.read_fraction)
+    rf = float(h.read_fraction)
     return "ddr5" if (rf >= 0.8 or rf <= 0.2) else "cxl"
+
+
+def preferred_tier(hint: MemoryHint) -> str:
+    """Host-tier preference for a scope's spilled blocks (§3 placement):
+    the tier its resolved hint carries."""
+    return hint.resolved().tier
 
 
 def _split(path: str) -> list[str]:
@@ -130,14 +136,14 @@ class HintTree:
         exactly like reading an unset cgroup attribute.
         """
         parts = _split(path) if path != "/" else []
-        merged = self._hints.get("/", MemoryHint()).merged_over(SYSTEM_DEFAULT)
+        merged = self._hints.get("/", MemoryHint())
         prefix = ""
         for part in parts:
             prefix += "/" + part
             node = self._hints.get(prefix)
             if node is not None:
                 merged = node.merged_over(merged)
-        return merged
+        return merged.resolved()
 
     def paths(self) -> Iterator[str]:
         return iter(sorted(self._hints))

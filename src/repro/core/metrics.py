@@ -7,9 +7,8 @@ addressed by a flat dotted name. ``ServeEngine.metrics()`` builds one
 per call — counters and gauges from the stats dicts, histograms from
 the tracer's boundary spans (when tracing is on), and the engine's
 ``CaxRegistry`` scope tree under ``"cax"`` — so every consumer (the
-serve CLI's ``--telemetry`` report, the benchmarks' BENCH sections, a
-future cluster router) reads ONE snapshot shape instead of key-guarding
-three dict families.
+serve CLI's ``--telemetry`` report, a future cluster router) reads ONE
+snapshot shape instead of key-guarding three dict families.
 
 Unified stats schema
 --------------------
@@ -50,11 +49,6 @@ key                         meaning
 ``mesh``/``ici``            sharded engines only: mesh axis sizes + the
                             ``IciMeter`` summary
 ==========================  =============================================
-
-Sections that land in ``BENCH_serve.json`` additionally carry
-``phase_us`` (plan/dispatch/reconcile host-clock totals from the trace
-plane) and ``duplex_util.<channel>`` (per-channel busy fraction of the
-modelled transaction clock) — see README "Observability".
 """
 
 from __future__ import annotations

@@ -20,7 +20,7 @@ switch counts, migration volume, backlog (latency proxy via Little's law).
 
 This engine is used three ways:
   * microbenchmark reproduction (benchmarks/characterization, microbench),
-  * application workloads (redis_like, llm_inference, vectordb),
+  * the paper's Fig. 6 LLM phases (benchmarks/llm_inference),
   * planning real duplex offload transfers (core/offload.py) — the same
     policy decides the page-in/page-out interleave order.
 """

@@ -22,7 +22,6 @@ import json
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro import configs as configs_lib
@@ -192,8 +191,6 @@ def main() -> int:
     p.add_argument("--no-warmup", action="store_true",
                    help="skip the compile warmup pass (reported tok/s "
                         "then includes one-time XLA compilation)")
-    p.add_argument("--offload-demo", action="store_true",
-                   help="also run the legacy synthetic tiered-KV demo")
     args = p.parse_args()
 
     enable_compile_cache()
@@ -487,20 +484,6 @@ def main() -> int:
     if args.telemetry:
         report["telemetry"] = _round(engine.telemetry.to_dict())
     print(json.dumps(report))
-
-    if args.offload_demo:
-        from repro.runtime.serve import OffloadedKVCache
-        kv = OffloadedKVCache(n_blocks=64, hbm_blocks=16,
-                              block_shape=(16, 64))
-        for b in range(64):                 # fill + spill real data to host
-            kv.write_block(b, jnp.ones((16, 64)) * b)
-        for start in range(0, 48, 8):       # real ins co-issued with outs
-            kv.touch(list(range(start, start + 8)))
-        print("offload demo stats:", json.dumps(
-            {k: round(v, 2) if isinstance(v, float) else v
-             for k, v in kv.stats.items()}))
-        print(f"duplex vs phase-separated paging: "
-              f"{kv.duplex_speedup():.2f}x")
     return 0
 
 

@@ -1,2 +1,1 @@
 from repro.runtime.train import Trainer, TrainConfig, FaultInjector
-from repro.runtime.serve import DecodeServer, OffloadedKVCache, ServeConfig

@@ -1,7 +1,6 @@
 """ServeEngine — continuous-batching decode over the duplex-paged KV pool.
 
-The step loop (``ServeEngine.step``) replaces the old static
-``DecodeServer.generate`` batch loop:
+The step loop (``ServeEngine.step``):
 
   1. **admission** — free batch slots are offered to the ``RequestQueue``;
      the queue's ``core.policies`` policy picks which arrived prefills join

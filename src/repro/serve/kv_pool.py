@@ -1,8 +1,6 @@
 """Vectorized tiered KV block pool — the serving memory hierarchy.
 
-Replaces the per-request ``OffloadedKVCache`` (Python ``dict``/``list`` LRU,
-per-block ``.at[].set`` updates) with one pool shared by every request in
-the batch:
+One pool shared by every request in the batch:
 
   * residency, the slot map, and last-use clocks are **host numpy** arrays
     (``slot_of``, ``block_at``, ``last_use``) — they never participate in

@@ -223,20 +223,27 @@ class TestPoisonQuarantine:
 
 
 class TestOfflineEvacuation:
-    def test_hot_unplug_evacuates(self, api, params, baseline):
+    @pytest.mark.parametrize("plan,casualties", [
+        ("offline:2@8", {"evacuation_casualty", "shed"}),
+        # transients before and poisoned blocks after the unplug
+        ("transient:0@2+40=0.4,offline:2@8,poison:0@10,poison:1@11",
+         {"evacuation_casualty", "shed", "poisoned_block"}),
+    ], ids=["unplug", "unplug-transient-poison"])
+    def test_hot_unplug_evacuates(self, api, params, baseline, plan,
+                                  casualties):
         """Mid-serve channel hot-unplug: live host rows move to the
         surviving channels through the billed migration path, the dead
         channel holds nothing afterwards, placement never touches it
         again, and the survivors stay bit-exact."""
         oracle, _ = baseline
-        fx = FaultInjector(parse_fault_plan("offline:2@8"), seed=1)
+        fx = FaultInjector(parse_fault_plan(plan), seed=1)
         eng, reqs, outs = _serve(api, params, faults=fx,
                                  tiers="ddr5:1,cxl:2")
         f = eng.stats()["faults"]
         assert f["offline_channels"] == [2]
         assert f["evacuated"] > 0 and f["recovered"] >= f["evacuated"]
-        _check_survivors(eng, reqs, outs, oracle,
-                         {"evacuation_casualty", "shed"})
+        assert outs, "no survivors"
+        _check_survivors(eng, reqs, outs, oracle, casualties)
         host = eng.pool.host
         assert bool(host.offline[2])
         ts = eng.pool.tier_stats()

@@ -1,5 +1,7 @@
 """Hint tree (cgroup analogue) — inheritance, override, serialization."""
 
+import dataclasses
+
 import pytest
 
 pytest.importorskip("hypothesis")
@@ -36,6 +38,17 @@ class TestInheritance:
         t.set("/serve", MemoryHint(duplex_opt_in=False))
         assert t.resolve("/serve/prefill/attn").duplex_opt_in is False
 
+    def test_derived_tier_not_inherited(self):
+        """A scope that names no tier gets one from its own traffic mix:
+        the parent's derived tier does not flow down, its set one does."""
+        t = HintTree()
+        t.set("/job", MemoryHint(read_fraction=0.5))
+        t.set("/job/writer", MemoryHint(read_fraction=0.1))
+        assert t.resolve("/job").tier == "cxl"
+        assert t.resolve("/job/writer").tier == "ddr5"
+        t.set("/job", MemoryHint(read_fraction=0.5, tier="cxl"))
+        assert t.resolve("/job/writer").tier == "cxl"
+
     def test_intermediate_scopes_materialized(self):
         t = HintTree()
         t.set("/a/b/c", MemoryHint(priority=3.0))
@@ -68,6 +81,7 @@ _hints = st.builds(
     priority=st.one_of(st.none(), st.floats(0.1, 10)),
     phase_period_us=st.one_of(st.none(), st.floats(0, 1e4)),
     duplex_opt_in=st.one_of(st.none(), st.booleans()),
+    tier=st.one_of(st.none(), st.sampled_from(["ddr5", "cxl"])),
 )
 _segments = st.lists(st.sampled_from(["a", "b", "serve", "llm", "x1"]),
                      min_size=1, max_size=5)
@@ -77,10 +91,22 @@ def _path(segments):
     return "/" + "/".join(segments)
 
 
+def _derived_tier(r):
+    """The placement rule for a path that sets no tier, restated from
+    the paper's §3: withdrawn or unidirectional scopes go to DDR5, mixed
+    ones to CXL."""
+    if r.duplex_opt_in is False:
+        return "ddr5"
+    return "ddr5" if (r.read_fraction >= 0.8 or r.read_fraction <= 0.2) \
+        else "cxl"
+
+
 class TestResolutionProperties:
     """Property-based contracts of hierarchical resolution: inheritance
     is idempotent, children win, re-registration replaces, and
-    ``resolved()`` never leaves an unset field — at any depth."""
+    ``resolved()`` never leaves an unset field — at any depth. A tier
+    set on the path wins; an unset one is derived at the leaf and never
+    inherited."""
 
     @settings(max_examples=50, deadline=None)
     @given(segs=_segments, hints=st.lists(_hints, min_size=1, max_size=5))
@@ -115,6 +141,8 @@ class TestResolutionProperties:
                 want = getattr(parent, f)
             if want is None:
                 want = getattr(SYSTEM_DEFAULT, f)
+            if want is None and f == "tier":
+                want = _derived_tier(r)
             assert getattr(r, f) == want
 
     @settings(max_examples=50, deadline=None)
@@ -142,6 +170,8 @@ class TestResolutionProperties:
         merged = MemoryHint().merged_over(SYSTEM_DEFAULT)
         for i in range(len(segs)):
             merged = hints[i % len(hints)].merged_over(merged)
+        if merged.tier is None:
+            merged = dataclasses.replace(merged, tier=_derived_tier(merged))
         assert t.resolve(_path(segs)) == merged
 
 

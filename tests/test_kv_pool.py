@@ -22,7 +22,8 @@ def _fill(pool, blocks):
     """Make ``blocks`` resident and write real data into them."""
     blocks = list(blocks)
     pool.step(blocks)
-    pool.write(blocks, jnp.stack([_rand(b) for b in blocks]))
+    pool.write(blocks, jnp.stack([_rand(b, pool.block_shape)
+                                  for b in blocks]))
 
 
 class TestResidency:
@@ -93,8 +94,9 @@ class TestResidency:
 
 
 class TestLRU:
-    def test_eviction_order(self):
-        pool = _pool(hbm=2)
+    @pytest.mark.parametrize("n,shape", [(16, (8, 32)), (8, (4, 4))])
+    def test_eviction_order(self, n, shape):
+        pool = _pool(n=n, hbm=2, shape=shape)
         pool.step([0])
         pool.step([1])
         pool.step([0])          # 0 is now most-recent
@@ -127,9 +129,13 @@ class TestLRU:
 
 
 class TestRoundTrip:
-    def test_int8_roundtrip_tolerance(self):
-        pool = _pool(n=8, hbm=2)
-        data = {b: _rand(b) for b in range(4)}
+    @pytest.mark.parametrize("n,hbm,shape,n_data", [
+        (8, 2, (8, 32), 4),
+        (12, 4, (8, 16), 8),
+    ])
+    def test_int8_roundtrip_tolerance(self, n, hbm, shape, n_data):
+        pool = _pool(n=n, hbm=hbm, shape=shape)
+        data = {b: _rand(b, shape) for b in range(n_data)}
         for b, x in data.items():
             pool.step([b])
             pool.write([b], x[None])     # later steps evict earlier blocks
@@ -156,10 +162,12 @@ class TestBatchedPaging:
         assert pool.stats["kernel_calls"] == 7    # still one for the batch
         assert pool.stats["page_ins"] == 8
 
-    def test_duplex_speedup_on_mixed_batches(self):
-        pool = _pool(n=32, hbm=8)
-        for start in range(0, 32, 8):          # fill + spill to host
-            _fill(pool, range(start, start + 8))
+    @pytest.mark.parametrize("shape,fill_group", [((8, 32), 8),
+                                                   ((8, 16), 1)])
+    def test_duplex_speedup_on_mixed_batches(self, shape, fill_group):
+        pool = _pool(n=32, hbm=8, shape=shape)
+        for start in range(0, 32, fill_group):     # fill + spill to host
+            _fill(pool, range(start, start + fill_group))
         pool.reset_stats()
         for start in range(0, 24, 4):          # real ins co-issued w/ outs
             _fill(pool, range(start, start + 4))   # rewrite -> dirty evict

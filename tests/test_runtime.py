@@ -1,18 +1,11 @@
-"""Training/serving runtime: fault retry, resume, stragglers, elastic DP,
-tiered KV paging.
-
-This module is the shim test for the deprecated ``repro.runtime.serve``
-surface (DecodeServer / OffloadedKVCache) — the only test module allowed
-to import it; everything else drives ``repro.serve`` directly."""
+"""Training runtime: fault retry, resume, stragglers, elastic DP."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.models import registry as R
 from repro.optim import AdamWConfig
-from repro.runtime.serve import DecodeServer, OffloadedKVCache, ServeConfig
 from repro.runtime.train import FaultInjector, TrainConfig, Trainer
 
 
@@ -97,67 +90,3 @@ class TestElasticResume:
             full["tokens"],
             np.concatenate([h["tokens"] for h in halves]))
 
-
-class TestServing:
-    def test_shims_warn_with_caller_stacklevel(self):
-        """The deprecation shims name the real call site
-        (stacklevel=2), so downstream users see *their* line."""
-        import warnings
-        with pytest.warns(DeprecationWarning, match="ServeEngine"):
-            DecodeServer(_api(), None, ServeConfig())
-        with warnings.catch_warnings(record=True) as rec:
-            warnings.simplefilter("always")
-            OffloadedKVCache(n_blocks=4, hbm_blocks=2, block_shape=(4, 4))
-        dep = [w for w in rec if issubclass(w.category,
-                                            DeprecationWarning)]
-        assert dep and "PagedKVPool" in str(dep[0].message)
-        assert dep[0].filename == __file__      # stacklevel=2 -> caller
-
-    def test_greedy_deterministic(self):
-        api = _api()
-        params = api.init(jax.random.PRNGKey(0))
-        srv = DecodeServer(api, params, ServeConfig(cache_len=64))
-        prompts = jnp.ones((2, 4), jnp.int32)
-        a = srv.generate(prompts, 8)
-        b = srv.generate(prompts, 8)
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-    def test_kv_paging_roundtrip(self):
-        kv = OffloadedKVCache(n_blocks=12, hbm_blocks=4,
-                              block_shape=(8, 16))
-        data = {b: jax.random.normal(jax.random.PRNGKey(b), (8, 16)
-                                     ).astype(jnp.bfloat16)
-                for b in range(8)}
-        for b, x in data.items():
-            kv.write_block(b, x)
-        for b, x in data.items():
-            back = kv.read_block(b)
-            # int8 quantization bound: amax/127
-            amax = float(jnp.max(jnp.abs(x.astype(jnp.float32))))
-            err = float(jnp.max(jnp.abs(back.astype(jnp.float32)
-                                        - x.astype(jnp.float32))))
-            assert err <= amax / 127.0 + 0.02
-
-    def test_batched_paging_duplexes(self):
-        kv = OffloadedKVCache(n_blocks=32, hbm_blocks=8,
-                              block_shape=(8, 16))
-        for b in range(32):                  # fill + spill real data
-            kv.write_block(b, jnp.ones((8, 16)) * b)
-        kv.stats = {"page_ins": 0, "page_outs": 0, "duplex_us": 0.0,
-                    "serial_us": 0.0}
-        for start in range(0, 24, 4):        # real ins co-issued with outs
-            kv.touch(list(range(start, start + 4)))
-            for b in range(start, start + 4):     # rewrite -> dirty evict
-                kv.write_block(b, jnp.ones((8, 16)) * (b + 1))
-        assert kv.stats["page_ins"] > 0 and kv.stats["page_outs"] > 0
-        assert kv.duplex_speedup() > 1.3
-
-    def test_lru_eviction_order(self):
-        kv = OffloadedKVCache(n_blocks=8, hbm_blocks=2,
-                              block_shape=(4, 4))
-        kv.touch([0])
-        kv.touch([1])
-        kv.touch([0])          # 0 is now most-recent
-        kv.touch([2])          # evicts 1 (LRU), not 0
-        assert 0 in kv.resident and 2 in kv.resident
-        assert 1 not in kv.resident

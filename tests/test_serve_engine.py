@@ -65,19 +65,24 @@ class TestContinuousBatching:
         assert len(set(done)) > 1 and len(set(adm)) > 1
         assert eng.paging_stats()["page_ins"] > 0
 
-    def test_slot_reuse_after_completion(self, api, params):
+    @pytest.mark.parametrize("runs", [1, 2], ids=["once", "twice"])
+    def test_slot_reuse_after_completion(self, api, params, runs):
         """More requests than slots: retired slots are recycled and the
-        recycled slot's stale cache never leaks into new requests."""
+        recycled slot's stale cache never leaks into new requests. Run
+        twice on fresh engines, greedy serving repeats itself exactly."""
         prompts = jax.random.randint(jax.random.PRNGKey(2), (6, 5), 0,
                                      api.cfg.vocab)
         ref = np.asarray(reference_decode(api, params, prompts, 8,
                                           cache_len=64))
-        eng = ServeEngine(api, params, _cfg(max_batch=2))
-        rids = [eng.submit(np.asarray(prompts[i]), 8).rid
-                for i in range(6)]
-        outs = eng.run(max_steps=400)
-        for i, rid in enumerate(rids):
-            np.testing.assert_array_equal(outs[rid], ref[i])
+        served = []
+        for _ in range(runs):
+            eng = ServeEngine(api, params, _cfg(max_batch=2))
+            rids = [eng.submit(np.asarray(prompts[i]), 8).rid
+                    for i in range(6)]
+            outs = eng.run(max_steps=400)
+            served.append(np.stack([outs[rid] for rid in rids]))
+            np.testing.assert_array_equal(served[-1], ref)
+        np.testing.assert_array_equal(served[0], served[-1])
 
     def test_recurrent_state_reset_on_slot_reuse(self):
         """Non-attention caches (RWKV recurrent state) must also be wiped

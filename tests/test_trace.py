@@ -214,6 +214,10 @@ class TestPerfettoExport:
         chan_tracks = {k for k, n in thread_meta.items()
                        if n.endswith((".rd", ".wr"))}
         assert chan_tracks & set(by_track), "no channel busy slices"
+        # one timeline per tier of the channel set
+        tracks = sorted(eng.tracer.timelines)
+        assert any(t.startswith("ddr5:") for t in tracks), tracks
+        assert any(t.startswith("cxl:") for t in tracks), tracks
 
     def test_fault_instants_in_trace(self, api, params, tmp_path):
         from repro.core.faults import FaultInjector, parse_fault_plan
